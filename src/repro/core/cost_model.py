@@ -41,13 +41,25 @@ from repro.core.precision_plan import (DEVICE, HOST, PEER, PrecisionPlan,
 RUNG_QUALITY_COST: Dict[int, float] = {4: 0.07, 8: 0.02, 16: 0.0}
 
 
+#: Published per-chip peaks keyed by ``jax.Device.device_kind`` (the
+#: one table of them). "TPU v5 lite" is TPU v5e; source: Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9,
+                    "hbm_bytes": 16e9},
+}
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+
+
 @dataclasses.dataclass(frozen=True)
 class HardwareModel:
-    """Defaults: one TPU v5e chip + PCIe gen4-ish host link (DESIGN.md §2)."""
-    peak_flops: float = 197e12          # bf16 FLOP/s
-    hbm_bw: float = 819e9               # B/s
-    host_link_bw: float = 24e9          # B/s effective host->HBM
-    hbm_bytes: float = 16e9
+    """Defaults: one TPU v5e chip (``DEVICE_PEAKS``) + a PCIe gen4-ish
+    host link (DESIGN.md §2). Serving picks its peaks per device with
+    :func:`hardware_for_device`."""
+    peak_flops: float = _V5E["peak_flops"]   # bf16 FLOP/s
+    hbm_bw: float = _V5E["hbm_bw"]           # B/s
+    host_link_bw: float = 24e9               # B/s effective host->HBM
+    hbm_bytes: float = _V5E["hbm_bytes"]
     # Serving decode is memory-bound; effective MBU for weight streaming.
     mbu: float = 0.6
     mfu: float = 0.4
@@ -105,6 +117,21 @@ class HardwareModel:
         if bits >= 16:
             return 1.0
         return {4: self.q4_speedup_decode, 8: self.q8_speedup_decode}[bits]
+
+
+def hardware_for_device(device, **fields) -> HardwareModel:
+    """A :class:`HardwareModel` with ``device``'s peaks from
+    ``DEVICE_PEAKS``, plus ``fields``. The CPU backend keeps the default
+    (v5e) figures as a stand-in for tests — they describe no CPU — so the
+    frontier golden fixture is unchanged. Any other kind not in the table
+    raises rather than guessing."""
+    if device.platform == "cpu":
+        return HardwareModel(**fields)
+    if device.device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {device.device_kind!r}; "
+            "add them, with their source, to cost_model.DEVICE_PEAKS")
+    return HardwareModel(**DEVICE_PEAKS[device.device_kind], **fields)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -171,10 +171,13 @@ def _dispatch_local(x, ids, weights, *, rank, totals, locs, capacity):
 
 
 def _combine_local(ybuf, dest, tok, w_sorted, t, d):
+    """Each token's routed, weighted expert outputs summed in f32 (the
+    caller rounds once, after the EP all-reduce)."""
     flat = ybuf.reshape(-1, ybuf.shape[-1])
     contrib = jnp.take(flat, dest, axis=0, mode="fill", fill_value=0)
     contrib = contrib * w_sorted[:, None].astype(contrib.dtype)
-    return jnp.zeros((t, d), ybuf.dtype).at[tok].add(contrib)
+    return jnp.zeros((t, d), jnp.float32).at[tok].add(
+        contrib.astype(jnp.float32))
 
 
 # --------------------------------------------------------------------------
@@ -203,8 +206,8 @@ def _ffn_bf16(bank, xb, act, use_kernel: bool = False):
 
 
 def _ffn_q(bank, xb, act, use_kernel: bool):
-    """Quantized bank: fused Pallas kernel (serving) or dequant reference
-    (dry-run lowering — FLOP/byte-equivalent, see kernels/ops.py)."""
+    """Quantized bank: fused Pallas kernel (serving on a TPU) or the jnp
+    dequantize-then-einsum reference the kernels are checked against."""
     if use_kernel:
         from repro.kernels.ops import q_expert_matmul
         up = q_expert_matmul(xb, bank["w_up"])
@@ -328,6 +331,21 @@ def _bank_specs(banks, moe: MoEConfig, par: MoEParallelism,
     return walk(banks), ep
 
 
+def serve_shardings(serve_params, moe: MoEConfig, mesh):
+    """Shardings that place serve-layout params on an EP serving mesh
+    (``launch.mesh.make_ep_mesh``) as the step reads them: each
+    layer-stacked rung bank split like ``moe_apply``'s shard_map
+    in_specs, every other leaf replicated. Placed once per replan, the
+    step then moves no weights."""
+    par = MoEParallelism(mesh=mesh, dp_axes=("data",))
+    specs, _ = _bank_specs(serve_params["layers"]["moe"]["banks"], moe, par)
+    rep = jax.sharding.NamedSharding(mesh, P())
+    out = jax.tree_util.tree_map(lambda _: rep, serve_params)
+    out["layers"]["moe"]["banks"] = jax.tree_util.tree_map(
+        lambda s: jax.sharding.NamedSharding(mesh, P(None, *s)), specs)
+    return out
+
+
 def moe_apply(banks, x: jax.Array, weights: jax.Array, ids: jax.Array,
               moe: MoEConfig, par: MoEParallelism, *, act: str = "swiglu",
               use_kernel: bool = False,
@@ -407,25 +425,19 @@ def moe_apply(banks, x: jax.Array, weights: jax.Array, ids: jax.Array,
             # token shard in one collective
             y = jax.lax.psum_scatter(y, par.fsdp_axis, scatter_dimension=0,
                                      tiled=True)
-        return jax.lax.psum(y, par.ep_axis)
+        # a token's experts may sit on different ranks: the all-reduce
+        # sums in f32 and the sum is rounded once, whatever the ep (a
+        # bf16 all-reduce on the chip rounded otherwise than the
+        # single-device scatter-add: ep=4 and ep=1 differed by a ulp)
+        return jax.lax.psum(y, par.ep_axis).astype(ybuf.dtype)
 
-    if hasattr(jax, "shard_map"):                    # jax >= 0.6
-        fn = jax.shard_map(
-            local_fn,
-            mesh=par.mesh,
-            in_specs=(bank_specs, dp, dp, dp),
-            out_specs=dp,
-            check_vma=False,
-        )
-    else:                                            # 0.4.x compat
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(
-            local_fn,
-            mesh=par.mesh,
-            in_specs=(bank_specs, dp, dp, dp),
-            out_specs=dp,
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        local_fn,
+        mesh=par.mesh,
+        in_specs=(bank_specs, dp, dp, dp),
+        out_specs=dp,
+        check_vma=False,
+    )
     return fn(banks, x, weights, ids)
 
 
@@ -449,24 +461,35 @@ def build_ladder_banks(moe_params: Dict[str, jax.Array], bits_row,
     ladder rung gets a bank key (``None`` when empty) so per-layer bank
     pytrees stack cleanly across a balanced plan."""
     bits_row = np.asarray(bits_row)
-    rungs = sorted(ladder)
-    order = np.concatenate(
-        [np.where(bits_row == b)[0] for b in rungs]).astype(np.int32)
-    perm = {k: jnp.take(moe_params[k], order, axis=0)
-            for k in ("w_gate", "w_up", "w_down")}
     banks: Dict[str, Any] = {}
-    off = 0
-    for b in rungs:
-        cnt = int((bits_row == b).sum())
+    order = []
+    for b in sorted(ladder):
+        # gather each bank's experts straight from the layer (no full
+        # permuted copy of it: at Mixtral widths a layer is 2.8 GB)
+        idx = np.where(bits_row == b)[0]
+        order.append(idx)
         name = _bank_name(b)
-        if cnt == 0:
+        if not len(idx):
             banks[name] = None
             continue
-        sl = {k: v[off:off + cnt] for k, v in perm.items()}
-        banks[name] = sl if b >= 16 else \
-            {k: quantize(v, b, group_size) for k, v in sl.items()}
-        off += cnt
-    return banks, order
+        if b >= 16:
+            banks[name] = {k: jnp.take(moe_params[k], idx, axis=0)
+                           for k in ("w_gate", "w_up", "w_down")}
+        else:
+            banks[name] = {k: _quantize_experts(
+                moe_params[k], jnp.asarray(idx, jnp.int32), b, group_size)
+                           for k in ("w_gate", "w_up", "w_down")}
+    return banks, np.concatenate(order).astype(np.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "group_size"))
+def _quantize_experts(w: jax.Array, idx: jax.Array, bits: int,
+                      group_size: int) -> QTensor:
+    """``quantize(w[idx])``, one expert per loop step: the same bits
+    (quantization is per expert), with f32 temporaries of one expert
+    instead of a whole bank, which at Mixtral widths are GBs, more than
+    one chip has to spare."""
+    return jax.lax.map(lambda i: quantize(w[i], bits, group_size), idx)
 
 
 def build_mixed_banks(moe_params: Dict[str, jax.Array], quant_mask,

@@ -1,7 +1,9 @@
-"""Fault-tolerant checkpointing: msgpack+zstd codec, atomic commit, keep-N
+"""Fault-tolerant checkpointing: msgpack+zlib codec, atomic commit, keep-N
 retention, async save thread, and reshard-on-load for elastic rescaling.
 
-Layout:  <dir>/step_<N>/ {manifest.json, shard_000.msgpack.zst, ...}
+Layout:  <dir>/step_<N>/ {manifest.json, tree.msgpack.zst}
+         (the payload is zlib; the file keeps its older name so that
+         checkpoints written with the zlib codec before still load)
          <dir>/step_<N>.COMMITTED        (atomic marker, written last)
 
 Restore never requires the saving mesh: arrays are stored unsharded
@@ -19,6 +21,7 @@ import os
 import shutil
 import threading
 import time
+import zlib
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
 
@@ -26,12 +29,6 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-
-try:
-    import zstandard
-except ImportError:          # container without the wheel: stdlib fallback
-    zstandard = None
-    import zlib
 
 _FLAG = "COMMITTED"
 
@@ -84,22 +81,11 @@ def encode_tree(tree, level: int = 3) -> bytes:
         arr = np.asarray(v)
         payload[k] = {"d": arr.dtype.name, "s": list(arr.shape),
                       "b": arr.tobytes()}
-    raw = msgpack.packb(payload, use_bin_type=True)
-    if zstandard is None:
-        return zlib.compress(raw, level)
-    return zstandard.ZstdCompressor(level=level).compress(raw)
+    return zlib.compress(msgpack.packb(payload, use_bin_type=True), level)
 
 
 def decode_tree(data: bytes):
-    if data[:4] == b"\x28\xb5\x2f\xfd":        # zstd frame magic
-        if zstandard is None:
-            raise RuntimeError("checkpoint is zstd-compressed but the "
-                               "zstandard module is unavailable")
-        raw = zstandard.ZstdDecompressor().decompress(data)
-    else:                                       # zlib fallback frame
-        import zlib as _zlib
-        raw = _zlib.decompress(data)
-    payload = msgpack.unpackb(raw, raw=False)
+    payload = msgpack.unpackb(zlib.decompress(data), raw=False)
     flat = {}
     for k, v in payload.items():
         if v is None:

@@ -14,7 +14,7 @@ where ``G`` is the number of experts in the rung's bank and ``C`` the
 capacity-bounded tokens-per-expert buffer the MoE dispatch packs
 (``mixed_moe._dispatch_local``). Each grid step indexes that group's packed
 weights/scales through its BlockSpec and dequantizes **in VMEM** right
-before the MXU dot, exactly like the per-expert kernel body
+before the MXU dot with the per-expert kernel's own ``dequant_tile``
 (``q4_matmul``) — per-tile arithmetic is identical, so the grouped q4/q8
 results are bit-exact against the per-expert loop (tested). The bf16 bank
 gets the same grouped layout without the dequant (f32 accumulation, so
@@ -39,25 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer jax renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def _dequant_tile(wq, sc, *, bits: int, group_size: int, block_k: int):
-    """Unpack + scale one (BK, BN) weight tile in VMEM (f32) — the same
-    arithmetic as the per-expert kernel bodies in ``q4_matmul``."""
-    if bits == 4:
-        # byte b holds K indices (2b, 2b+1) as (low, high) nibbles
-        lo = (wq & 0xF).astype(jnp.int8) - 8
-        hi = (wq >> 4).astype(jnp.int8) - 8
-        w_int = jnp.stack([lo, hi], axis=1).reshape(block_k, wq.shape[1])
-    else:
-        w_int = wq                                     # (BK, BN) int8
-    sc = sc.astype(jnp.float32)                        # (BK/G, BN)
-    w_f = w_int.astype(jnp.float32).reshape(
-        block_k // group_size, group_size, -1) * sc[:, None, :]
-    return w_f.reshape(block_k, -1)
+from repro.kernels.q4_matmul import dequant_tile
 
 
 def _grouped_q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
@@ -70,8 +52,8 @@ def _grouped_q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_f = _dequant_tile(wq_ref[0], sc_ref[0], bits=bits,
-                        group_size=group_size, block_k=block_k)
+    w_f = dequant_tile(wq_ref[0], sc_ref[0], bits=bits,
+                       group_size=group_size, block_k=block_k)
     acc_ref[...] += jax.lax.dot(
         x_ref[0].astype(jnp.float32), w_f,
         preferred_element_type=jnp.float32)
@@ -105,7 +87,7 @@ def grouped_quantized_matmul(
     group_size: int = 64,
     block_m: int = 128,
     block_n: int = 256,
-    block_k: int = 128,
+    block_k: int = 512,
     out_dtype=jnp.bfloat16,
     interpret: bool = False,
 ) -> jax.Array:
@@ -160,10 +142,11 @@ def grouped_quantized_matmul(
                                lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, c, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=f"grouped_q{bits}_matmul",
     )(x, wq, scales)
 
 
@@ -173,7 +156,7 @@ def grouped_bf16_matmul(
     *,
     block_m: int = 128,
     block_n: int = 256,
-    block_k: int = 128,
+    block_k: int = 512,
     out_dtype=jnp.bfloat16,
     interpret: bool = False,
 ) -> jax.Array:
@@ -203,8 +186,9 @@ def grouped_bf16_matmul(
                                lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, c, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="grouped_bf16_matmul",
     )(x, w)

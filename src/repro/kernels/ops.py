@@ -2,12 +2,15 @@
 
 These handle padding to tile boundaries, the QTensor container, batching
 over experts (vmap adds a leading grid dimension to the pallas_call), and
-CPU fallback (interpret mode executes the kernel body in Python — used for
-tests and for this CPU container; on TPU the same code JITs to Mosaic).
+the execution mode: on a TPU the kernels compile to Mosaic; on the CPU
+backend (tests) they run in Pallas interpret mode; any other backend has
+no kernel path and raises. The mode is decided when a wrapper is traced,
+never when this module is imported.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -17,10 +20,52 @@ from repro.core.quantization import QTensor
 from repro.kernels import grouped_matmul as _gk
 from repro.kernels import q4_matmul as _k
 
-# On the CPU container Pallas must run in interpret mode; flip to False on
-# real TPU (dryrun lowering for TPU targets uses the jnp reference path —
-# see mixed_moe.use_kernel).
-_DEFAULT_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """Resolve ``interpret=None`` from the default backend: compiled on a
+    TPU, interpreted on the CPU backend. Any other backend raises rather
+    than quietly running the kernel body in the Python interpreter."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas kernel path on backend {backend!r}")
+
+
+def serve_through_kernels() -> bool:
+    """The serving default for ``use_kernel``: the compiled kernels on a
+    TPU, the jnp dequant reference on the CPU backend (tests); any other
+    backend raises, as :func:`_interpret` does."""
+    return not _interpret(None)
+
+
+def _tile(dim: int, cap: int, quantum: int) -> int:
+    """The one tile chooser: the largest multiple of ``quantum`` that
+    divides ``dim`` and is <= max(cap, quantum), else the whole ``dim``.
+    Mosaic requires every block's last two dims to be multiples of
+    (8, 128) or to span the array; callers pass the quantum that makes
+    each of their BlockSpecs legal."""
+    best = dim
+    b = quantum
+    while b <= min(max(cap, quantum), dim):
+        if dim % b == 0:
+            best = b
+        b += quantum
+    return best
+
+
+def _q_tiles(qt: QTensor, block_n: int, block_k: int):
+    """(block_n, block_k) for a quantized weight. K tiles hold whole
+    multiples of 8 scale rows (``block_k // group_size``), which also
+    makes them multiples of 128 lanes for the activations; N tiles are
+    multiples of 128 lanes. Shared by the per-expert and grouped paths so
+    both pick identical tiles (their bit-exact parity depends on it)."""
+    k, n = qt.shape[-2:]
+    return (_tile(n, block_n, 128),
+            _tile(k, block_k, math.lcm(128, 8 * qt.group_size)))
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -48,21 +93,16 @@ def _with_padded_m(call, x: jax.Array, *, block_m: int, m_axis: int):
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "out_dtype", "interpret"))
 def q_matmul(x: jax.Array, qt: QTensor, *, block_m: int = 128,
-             block_n: int = 256, block_k: int = 128,
+             block_n: int = 256, block_k: int = 512,
              out_dtype=jnp.bfloat16,
              interpret: Optional[bool] = None) -> jax.Array:
     """``x @ dequant(qt)`` — (M, K) x Q(K, N) -> (M, N).
 
-    M is padded to the tile size (decode batches are small); K and N must
-    already satisfy tile divisibility (true for every config in the zoo —
-    d_model/d_ff are multiples of 256).
+    M is padded to the tile size (decode batches are small); K and N get
+    the largest legal tile that divides them (``_q_tiles``).
     """
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
-    k, n = qt.shape[-2:]
-    # shrink tiles to divisors (TP-sharded d_ff slices, e.g. 14336/16=896,
-    # are multiples of 128 but not of 256)
-    block_n = _largest_divisor(n, block_n, qt.group_size)
-    block_k = _largest_divisor(k, block_k, qt.group_size)
+    interpret = _interpret(interpret)
+    block_n, block_k = _q_tiles(qt, block_n, block_k)
     return _with_padded_m(
         lambda xp, bm: _k.quantized_matmul(
             xp, qt.q, qt.scales, bits=qt.bits, group_size=qt.group_size,
@@ -71,19 +111,8 @@ def q_matmul(x: jax.Array, qt: QTensor, *, block_m: int = 128,
         x, block_m=block_m, m_axis=0)
 
 
-def _largest_divisor(dim: int, cap: int, step: int) -> int:
-    """Largest multiple of ``step`` that divides ``dim`` and is <= cap."""
-    best = step if dim % step == 0 else dim
-    b = step
-    while b <= min(cap, dim):
-        if dim % b == 0:
-            best = b
-        b += step
-    return min(best, dim)
-
-
 def q_expert_matmul(x: jax.Array, qt: QTensor, *, block_m: int = 128,
-                    block_n: int = 256, block_k: int = 128,
+                    block_n: int = 256, block_k: int = 512,
                     out_dtype=jnp.bfloat16,
                     interpret: Optional[bool] = None,
                     grouped: bool = True) -> jax.Array:
@@ -96,7 +125,6 @@ def q_expert_matmul(x: jax.Array, qt: QTensor, *, block_m: int = 128,
     bit-identical to the grouped path and retained as the A/B baseline for
     ``benchmarks/kernel_bench.py``.
     """
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
     if grouped:
         return grouped_q_matmul(
             x, qt, block_m=block_m, block_n=block_n, block_k=block_k,
@@ -112,16 +140,14 @@ def q_expert_matmul(x: jax.Array, qt: QTensor, *, block_m: int = 128,
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "out_dtype", "interpret"))
 def grouped_q_matmul(x: jax.Array, qt: QTensor, *, block_m: int = 128,
-                     block_n: int = 256, block_k: int = 128,
+                     block_n: int = 256, block_k: int = 512,
                      out_dtype=jnp.bfloat16,
                      interpret: Optional[bool] = None) -> jax.Array:
     """One-launch grouped ``(E, C, K) x Q(E, K, N) -> (E, C, N)``. Tile
     selection mirrors :func:`q_matmul` exactly so the grouped result is
     bit-identical to the per-expert loop."""
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
-    k, n = qt.shape[-2:]
-    block_n = _largest_divisor(n, block_n, qt.group_size)
-    block_k = _largest_divisor(k, block_k, qt.group_size)
+    interpret = _interpret(interpret)
+    block_n, block_k = _q_tiles(qt, block_n, block_k)
     return _with_padded_m(
         lambda xp, bm: _gk.grouped_quantized_matmul(
             xp, qt.q, qt.scales, bits=qt.bits, group_size=qt.group_size,
@@ -133,16 +159,15 @@ def grouped_q_matmul(x: jax.Array, qt: QTensor, *, block_m: int = 128,
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "out_dtype", "interpret"))
 def grouped_bf16_matmul(x: jax.Array, w: jax.Array, *, block_m: int = 128,
-                        block_n: int = 256, block_k: int = 128,
+                        block_n: int = 256, block_k: int = 512,
                         out_dtype=jnp.bfloat16,
                         interpret: Optional[bool] = None) -> jax.Array:
     """One-launch grouped bf16 ``(E, C, K) x (E, K, N) -> (E, C, N)`` —
     the f16 bank's grouped path (no dequant; f32 VMEM accumulation, so
     parity with the einsum reference is allclose, not bitwise)."""
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     _, k, n = w.shape
-    block_n = _largest_divisor(n, block_n, 8)
-    block_k = _largest_divisor(k, block_k, 8)
+    block_n, block_k = _tile(n, block_n, 128), _tile(k, block_k, 128)
     return _with_padded_m(
         lambda xp, bm: _gk.grouped_bf16_matmul(
             xp, w, block_m=bm, block_n=block_n, block_k=block_k,

@@ -11,10 +11,13 @@ Design (DESIGN.md §2, hardware adaptation):
   * Grid (M/BM, N/BN, K/BK), revolving f32 accumulator in VMEM scratch;
     K is the innermost (fastest) grid axis so the accumulator tile stays
     resident while weight tiles stream through.
-  * Default tiles (BM, BN, BK) = (128, 256, 128): MXU-aligned (128 lanes),
-    VMEM footprint = x(128x128xbf16 = 32 KiB) + w(64x256 = 16 KiB packed)
-    + scales(2x256) + acc(128x256xf32 = 128 KiB) ~ 176 KiB << 16 MiB VMEM,
-    leaving room for double-buffered pipelining.
+  * Default tiles (BM, BN, BK) = (128, 256, 512), chosen by
+    ``ops._tile``: every block's last two dims are multiples of (8, 128)
+    or span the array, which Mosaic requires — for group 64 that means
+    BK/G >= 8 scale rows. VMEM per step = x(128x512xbf16 = 128 KiB) +
+    w(256x256 packed = 64 KiB) + scales(8x256xbf16 = 4 KiB) + acc
+    (128x256xf32 = 128 KiB) + the dequantized f32 tile (512 KiB), well
+    under v5e's scoped VMEM even double-buffered.
   * ``dot(int8-ish bf16 values)`` uses preferred_element_type=f32 so the MXU
     accumulates in f32.
 
@@ -24,20 +27,34 @@ The pure-jnp oracle lives in ``repro.kernels.ref``; jit'd public wrappers in
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer jax renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+
+def dequant_tile(wq, sc, *, bits: int, group_size: int, block_k: int):
+    """Unpack + scale one (BK, BN) weight tile in VMEM, in f32.
+
+    int4 bytes hold K indices (2b, 2b+1) as (low, high) nibbles. They are
+    widened to int32 before any mask, shift or subtract: the TPU vector
+    unit has no 8-bit integer ALU, so Mosaic refuses int8 arithmetic."""
+    if bits == 4:
+        w = wq.astype(jnp.int32)                       # (BK//2, BN)
+        lo = (w & 0xF) - 8
+        hi = (w >> 4) - 8
+        w_int = jnp.stack([lo, hi], axis=1).reshape(block_k, w.shape[1])
+    else:
+        w_int = wq                                     # (BK, BN) int8
+    sc = sc.astype(jnp.float32)                        # (BK/G, BN)
+    w_f = w_int.astype(jnp.float32).reshape(
+        block_k // group_size, group_size, -1) * sc[:, None, :]
+    return w_f.reshape(block_k, -1)
 
 
-def _q4_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
-               group_size: int, block_k: int):
+def _q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
+              group_size: int, block_k: int, bits: int):
     """One (BM, BN) output tile; K streamed over grid axis 2."""
     k = pl.program_id(2)
 
@@ -45,40 +62,8 @@ def _q4_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # unpack int4: byte b holds K indices (2b, 2b+1) as (low, high) nibbles
-    w8 = wq_ref[...]                                   # (BK//2, BN) uint8
-    lo = (w8 & 0xF).astype(jnp.int8) - 8
-    hi = (w8 >> 4).astype(jnp.int8) - 8
-    w_int = jnp.stack([lo, hi], axis=1).reshape(block_k, w8.shape[1])
-
-    # group-wise scale: (BK/G, BN) -> broadcast over each group's rows
-    sc = sc_ref[...].astype(jnp.float32)               # (BK/G, BN)
-    w_f = w_int.astype(jnp.float32).reshape(
-        block_k // group_size, group_size, -1) * sc[:, None, :]
-    w_f = w_f.reshape(block_k, -1)
-
-    acc_ref[...] += jax.lax.dot(
-        x_ref[...].astype(jnp.float32), w_f,
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _q8_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
-               group_size: int, block_k: int):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    w_int = wq_ref[...]                                # (BK, BN) int8
-    sc = sc_ref[...].astype(jnp.float32)
-    w_f = w_int.astype(jnp.float32).reshape(
-        block_k // group_size, group_size, -1) * sc[:, None, :]
-    w_f = w_f.reshape(block_k, -1)
+    w_f = dequant_tile(wq_ref[...], sc_ref[...], bits=bits,
+                       group_size=group_size, block_k=block_k)
     acc_ref[...] += jax.lax.dot(
         x_ref[...].astype(jnp.float32), w_f,
         preferred_element_type=jnp.float32)
@@ -97,7 +82,7 @@ def quantized_matmul(
     group_size: int = 64,
     block_m: int = 128,
     block_n: int = 256,
-    block_k: int = 128,
+    block_k: int = 512,
     out_dtype=jnp.bfloat16,
     interpret: bool = False,
 ) -> jax.Array:
@@ -128,12 +113,11 @@ def quantized_matmul(
         raise ValueError(f"group_size {group_size} must divide BK {block_k}")
 
     grid = (m // block_m, n // block_n, kdim // block_k)
-    kern = _q4_kernel if bits == 4 else _q8_kernel
     w_rows = block_k // 2 if bits == 4 else block_k
 
     return pl.pallas_call(
-        functools.partial(kern, nk=grid[2], group_size=group_size,
-                          block_k=block_k),
+        functools.partial(_q_kernel, nk=grid[2], group_size=group_size,
+                          block_k=block_k, bits=bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
@@ -144,7 +128,8 @@ def quantized_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=f"q{bits}_matmul",
     )(x, wq, scales)

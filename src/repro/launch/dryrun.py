@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from repro.configs import SHAPES, all_cells, get_config
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.dist import sharding as SH
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models.model import (abstract_params, apply_precision_plan,
                                 build_model, init_cache)
 from repro.training.train_loop import (TrainConfig, make_train_step,
@@ -132,7 +132,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         jit_kw = dict(in_shardings=in_sh)
         if out_sh is not None:
             jit_kw["out_shardings"] = out_sh
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jfn = jax.jit(fn, **jit_kw)
             lowered = jfn.lower(*args)
             t_lower = time.time() - t0

@@ -5,26 +5,13 @@ touches jax device state.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 
 def _make_mesh(shape, axes, devices):
-    """jax.make_mesh across versions (axis_types only where supported)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh: ``jax.set_mesh``
-    on new jax, the legacy global-mesh context on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh if hasattr(mesh, "__enter__") else contextlib.nullcontext()
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def _require_devices(ndev: int, shape) -> list:

@@ -102,6 +102,7 @@ from repro.core.dynamic_precision import DynamicPrecisionController
 from repro.core.expert_cache import AsyncExpertCache, ExpertCache
 from repro.core.sensitivity import SensitivityProfile, calibrate_sensitivity
 from repro.ft.checkpoint import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serving.api import (EngineConfig, MultiTenantEngine, QoSTarget,
                                RequestSLO, ServeRequest, TenantSpec,
@@ -279,6 +280,7 @@ def _serve_dp(args, cfg, params, profile=None):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b", choices=list(ARCH_IDS))
     # -- declarative QoS targets (DESIGN.md §9) -------------------------

@@ -25,6 +25,7 @@ from repro.data.pipeline import (DataPipeline, SyntheticCorpus,
                                  SyntheticCorpusConfig)
 from repro.dist import sharding as SH
 from repro.ft.checkpoint import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.training.optimizer import OptConfig
 from repro.training.train_loop import (TrainConfig, init_train_state,
@@ -32,6 +33,7 @@ from repro.training.train_loop import (TrainConfig, init_train_state,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCH_IDS))
     ap.add_argument("--steps", type=int, default=100)

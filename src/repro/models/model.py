@@ -705,16 +705,17 @@ def apply_precision_plan(params, cfg: ModelConfig, plan: PrecisionPlan):
         banks, order = mixed_moe.build_ladder_banks(
             layer_p, plan.bits[li], ladder=plan.ladder,
             group_size=plan.group_size)
+        del layer_p     # one layer's slice at a time (2.8 GB at Mixtral widths)
         banks_per_layer.append(banks)
         routers.append(jnp.take(moe_p["router"][li], order, axis=1))
     stacked = {}
-    for bank in banks_per_layer[0]:
-        if banks_per_layer[0][bank] is None:
-            stacked[bank] = None
-        else:
-            stacked[bank] = jax.tree_util.tree_map(
-                lambda *a: jnp.stack(a),
-                *[b[bank] for b in banks_per_layer])
+    for bank in list(banks_per_layer[0]):
+        # pop each bank's per-layer pieces as it is stacked, so at most
+        # one bank is held twice (device memory at published widths)
+        parts = [b.pop(bank) for b in banks_per_layer]
+        stacked[bank] = None if parts[0] is None else \
+            jax.tree_util.tree_map(lambda *a: jnp.stack(a), *parts)
+        del parts
     new_params = jax.tree_util.tree_map(lambda x: x, params)  # shallow copy
     new_params["layers"] = dict(params["layers"])
     new_params["layers"]["moe"] = {

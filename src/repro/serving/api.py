@@ -93,7 +93,13 @@ class EngineConfig:
         into the expert-residency axis.
     Hardware:
       * ``hw`` — analytic hardware model; None measures the host link
-        bandwidth once per process and uses defaults otherwise.
+        bandwidth once per process and takes the device's published
+        peaks (``cost_model.hardware_for_device``).
+      * ``use_kernel`` — expert FFN through the Pallas kernels (one per
+        rung). ``None`` (default) = on a TPU, and the jnp dequant
+        reference on the CPU test backend; any other backend raises.
+        ``False`` forces the reference, the oracle the kernels are
+        checked against.
     Speculative decode (DESIGN.md §17):
       * ``speculate`` — draft depth K for ladder-draft self-speculative
         decoding: each iteration drafts K tokens per slot with every
@@ -115,7 +121,7 @@ class EngineConfig:
     """
     max_slots: int = 8
     max_len: int = 256
-    use_kernel: bool = False
+    use_kernel: Optional[bool] = None
     max_active_tokens: Optional[int] = None
     max_queue: Optional[int] = None
     swap_bytes: Optional[int] = None

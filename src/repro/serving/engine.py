@@ -32,12 +32,13 @@ Architecture:
     only the exposed part. The sync path survives as ``overlap=False``
     for A/B comparison, and ``close()`` joins the transfer workers.
 
-Fidelity model on this CPU container (DESIGN.md §2): model compute is
-REAL (jitted decode with the plan's dual-bank mixed-precision params);
-expert streaming runs through the real LRU cache with real ``device_put``
-staging — on this single-memory container the jitted banks stay resident,
-so the transfers are measured but not consumed by the matmuls; on a TPU
-deployment the fetched buffers are donated into the step.
+Fidelity (DESIGN.md §2): model compute is REAL (jitted decode with the
+plan's per-rung banks; on a TPU the banks run through the Pallas
+kernels). Expert streaming runs through the real LRU cache with real
+``device_put`` staging, but the step does not read the staged copies:
+the serve banks hold EVERY expert on the device, host-tier experts
+included, whatever the plan's placement. Transfers are measured, not
+consumed by the matmuls, and device memory holds the whole model.
 
 Reconfiguration is safe mid-flight: placement-only replans apply between
 decode iterations without touching in-flight requests (placement never
@@ -68,14 +69,16 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.cost_model import (HardwareModel, estimate_qos,
-                                   expert_access_stats, kv_bytes_bucketed,
-                                   kv_token_bytes)
+                                   expert_access_stats, hardware_for_device,
+                                   kv_bytes_bucketed, kv_token_bytes)
 from repro.core.expert_cache import (AsyncExpertCache, ExpertCache,
                                      PrefetchingExpertCache)
+from repro.core.mixed_moe import serve_shardings
 from repro.core.pareto import FrontierPoint, ParetoFrontier, QoSTarget
 from repro.core.planner import AdaptivePlanner, PlanResult
 from repro.core.precision_plan import (DEVICE, HOST, PrecisionPlan,
                                        quantized_rungs)
+from repro.kernels.ops import serve_through_kernels
 from repro.models.model import Model, apply_precision_plan, build_model
 from repro.serving.api import EngineConfig, ServeRequest, ServeResult
 from repro.serving.metrics import base_metrics
@@ -135,7 +138,7 @@ class AdaptiveServingEngine:
                  config: Optional[EngineConfig] = None,
                  hw: Optional[HardwareModel] = None,
                  max_batch: int = 8, max_len: int = 256,
-                 use_kernel: bool = False,
+                 use_kernel: Optional[bool] = None,
                  max_active_tokens: Optional[int] = None,
                  max_queue: Optional[int] = None,
                  swap_bytes: Optional[int] = None,
@@ -160,7 +163,9 @@ class AdaptiveServingEngine:
         self.mesh = mesh
         self.max_slots = config.max_slots
         self.max_len = config.max_len
-        self.use_kernel = config.use_kernel
+        self.use_kernel = (serve_through_kernels()
+                           if config.use_kernel is None
+                           else config.use_kernel)
         if config.hw is not None:
             # an explicit hardware model wins, but an explicit
             # overlap_efficiency knob still applies on top (it would
@@ -178,8 +183,9 @@ class AdaptiveServingEngine:
             eff = config.overlap_efficiency
             if eff is None:
                 eff = 0.85 if config.overlap else 0.0
-            self.hw = HardwareModel(host_link_bw=measure_host_link_bw(),
-                                    overlap_efficiency=float(eff))
+            self.hw = hardware_for_device(
+                jax.devices()[0], host_link_bw=measure_host_link_bw(),
+                overlap_efficiency=float(eff))
         self.planner = AdaptivePlanner(cfg, hw=self.hw,
                                        ep=getattr(config, "ep", 1))
         self.model: Model = build_model(cfg, mesh,
@@ -483,8 +489,7 @@ class AdaptiveServingEngine:
                 # the OLD plan — barrier again before invalidating
                 self.expert_cache.drain()
             # bank split changed -> re-specialize the step functions
-            self._serve_params = apply_precision_plan(
-                self.params_train, self.cfg, plan)
+            self._serve_params = self._build_serve_params(plan)
             self._compiled.clear()
             self._host_store.clear()
             self.expert_cache.invalidate()
@@ -506,8 +511,7 @@ class AdaptiveServingEngine:
             # weights get served (shapes unchanged, so no recompile).
             rung_changed = set()
             if (prev_plan.bits != plan.bits).any():
-                self._serve_params = apply_precision_plan(
-                    self.params_train, self.cfg, plan)
+                self._serve_params = self._build_serve_params(plan)
                 rung_changed = {
                     (int(l), int(e)) for l, e in
                     np.argwhere(prev_plan.bits != plan.bits)}
@@ -541,9 +545,27 @@ class AdaptiveServingEngine:
                 + delta["traffic_bytes"]
         return result
 
+    def _build_serve_params(self, plan: PrecisionPlan):
+        """The plan's serve-layout params. On an EP mesh they are placed
+        on it here, banks sharded as the step reads them, so no step
+        moves weights; a single-device engine builds them where it
+        runs."""
+        params = apply_precision_plan(self.params_train, self.cfg, plan)
+        if self.mesh is None:
+            return params
+        return jax.device_put(params, serve_shardings(
+            params, self.cfg.moe, self.mesh))
+
     # ------------------------------------------------------------------
     # Dynamic precision (DESIGN.md §15)
     # ------------------------------------------------------------------
+    @property
+    def serve_params(self):
+        """The params the step runs on: the active plan's per-rung banks
+        plus the shared non-expert weights (None before the first
+        replan)."""
+        return self._serve_params
+
     @property
     def current_plan(self) -> Optional[PrecisionPlan]:
         """The active precision plan (None before the first replan)."""
@@ -605,8 +627,7 @@ class AdaptiveServingEngine:
         new_plan = dataclasses.replace(old_plan, bits=new_bits)
         # same bank shapes -> the jitted step functions stay valid; only
         # the bank contents and the router permutation change
-        self._serve_params = apply_precision_plan(
-            self.params_train, self.cfg, new_plan)
+        self._serve_params = self._build_serve_params(new_plan)
         self._plan_result = dataclasses.replace(
             self._plan_result, plan=new_plan,
             qos=estimate_qos(self.cfg, new_plan, self.planner.hw,

@@ -45,7 +45,7 @@ def assert_bit_equal(got, want):
 
 class TestGroupedQuantParity:
     #: (experts_in_group, capacity, K, N, group_size) — capacity sweeps
-    #: unaligned M tiles; K/N=192 force _largest_divisor tile shrinking;
+    #: unaligned M tiles; K/N=192 fall back to whole-dim tiles (ops._tile);
     #: group_size=32 exercises a non-default scale granularity
     CASES = [
         (1, 8, 128, 128, 64),

@@ -31,7 +31,6 @@ from repro.core import mixed_moe as MM
 from repro.configs.base import MoEConfig
 
 mesh = jax.make_mesh((4, 4), ("data", "model"))
-from repro.launch.mesh import use_mesh
 moe = MoEConfig(num_experts=8, top_k=2, d_ff_expert=64, capacity_factor=8.0)
 d, t = 32, 16
 ks = jax.random.split(jax.random.key(0), 5)
@@ -47,7 +46,7 @@ banks16 = {"q4": None,
            "f16": {k: params[k] for k in ("w_gate", "w_up", "w_down")}}
 w, ids, _ = MM.route(params["router"], x, moe, train=False)
 outs = {}
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     for fsdp in (None, "data"):
         par = MM.MoEParallelism(mesh=mesh, dp_axes=("data",),
                                 fsdp_axis=fsdp)
@@ -69,7 +68,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config, reduce_for_smoke
 from repro.core.precision_plan import balanced_ladder_plan
-from repro.launch.mesh import make_ep_mesh, use_mesh
+from repro.launch.mesh import make_ep_mesh
 from repro.models.model import apply_precision_plan, build_model
 
 cfg = reduce_for_smoke(get_config("mixtral-8x7b"))
@@ -88,12 +87,13 @@ plans = {
 tok = np.asarray(jax.random.randint(jax.random.key(1), (2, 8), 1,
                                     cfg.vocab_size))
 ref = {}
-for name, plan in plans.items():
+for name in PLANS:
+    plan = plans[name]
     sp = apply_precision_plan(params, cfg, plan)
     for ep in (1, 2, 4):
         mesh = None if ep == 1 else make_ep_mesh(ep)
-        model = build_model(cfg, mesh)
-        ctx = use_mesh(mesh) if mesh is not None \
+        model = build_model(cfg, mesh, use_kernel=USE_KERNEL)
+        ctx = jax.set_mesh(mesh) if mesh is not None \
             else contextlib.nullcontext()
         with ctx:
             cache = model.init_cache(2, 24)
@@ -164,6 +164,11 @@ print("OK")
 """
 
 
+def _parity_script(plans, use_kernel):
+    return (f"PLANS = {list(plans)!r}\nUSE_KERNEL = {use_kernel!r}\n"
+            + _PARITY_SCRIPT)
+
+
 def _run_sub(script, timeout=900):
     env = dict(os.environ,
                PYTHONPATH=os.path.abspath(
@@ -180,8 +185,16 @@ class TestEPDecodeParity:
         """prefill + 4 greedy decode steps: logits BYTES equal for
         EP ∈ {1, 2, 4}, on binary and mixed (16, 8, 4) plans, plus the
         rank-migration assertion across a replan."""
-        r = _run_sub(_PARITY_SCRIPT)
+        r = _run_sub(_parity_script(("binary", "mixed", "replan"), False))
         assert "OK" in r.stdout and "MIGRATION OK" in r.stdout, \
+            r.stdout + r.stderr
+
+    def test_kernel_decode_bit_identical_across_ep(self):
+        """The same on the mixed (16, 8, 4) plan through the Pallas
+        kernels (interpret mode here): the grouped kernels inside the
+        EP shard_map give ep=2 and ep=4 the bytes of ep=1."""
+        r = _run_sub(_parity_script(("mixed",), True))
+        assert r.returncode == 0 and "PARITY mixed OK" in r.stdout, \
             r.stdout + r.stderr
 
     def test_engine_tokens_identical_across_ep(self):
