@@ -1,0 +1,9 @@
+"""Device: the share of the traced window in which no operation ran on
+the chip (1 - busy / window, from the profiler trace), in %."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or tr["window_s"] <= 0 or tr["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

@@ -1,0 +1,13 @@
+"""Kernels: grouped_bf16_matmul's share of its roofline in the traced
+window: the least time of each call (the larger of its operations over
+the bf16 peak and its bytes over HBM bandwidth, from the call's shapes;
+``benchlib.roofline.KernelCall``), summed, over the summed device time
+of the kernel's events, in %."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    k = (tr or {}).get("kernels", {}).get("grouped_bf16_matmul")
+    if not k or k["device_s"] <= 0:
+        return None
+    return 100.0 * k["least_s"] / k["device_s"]
