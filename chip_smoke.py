@@ -253,7 +253,7 @@ def one_chip(cfg, params, seed: int, *, use_kernel=None) -> None:
         f"iterations {m['iterations']!r}")
     log(f"expert cache: {m['expert_fetches']!r} fetches of "
         f"{m['expert_accesses']!r} accesses, transfer_s "
-        f"{m['transfer_s']!r}, stage_s {m['stage_s']!r}")
+        f"{m['transfer_s']!r}")
     if not m["expert_fetches"]:
         raise AssertionError("no host-tier expert was staged")
     log(memory_line(jax.devices()[0], point.qos.device_bytes))

@@ -58,7 +58,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import threading
 import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
@@ -87,6 +86,7 @@ from repro.serving.sampler import sample, speculative_verify
 from repro.serving.scheduler import (ContinuousScheduler, Request,
                                      RequestSLO, SamplingParams,
                                      SchedulerConfig)
+from repro.serving.tracing import span
 
 __all__ = ["AdaptiveServingEngine", "Request", "measure_host_link_bw"]
 
@@ -302,10 +302,6 @@ class AdaptiveServingEngine:
                                       or 0))
         self._draft_params = None
         self._draft_sig: Optional[Tuple] = None
-        # async transfer workers call _fetch_expert concurrently: its
-        # host-store insert is per-key-unique (one in-flight future per
-        # key) but the stage_s accumulation needs the lock
-        self._stage_lock = threading.Lock()
         # the shared sim/real metric schema (repro.serving.metrics,
         # DESIGN.md §14.2) — controllers see the same dict shape against
         # the deterministic SimulatedEngine. KV notes (DESIGN.md §13):
@@ -678,8 +674,15 @@ class AdaptiveServingEngine:
         return ServeResult.from_request(self.scheduler.done[rid])
 
     def _jit(self, name, fn, donate=()):
+        """The jitted ``fn``, cached under ``name`` (a string, or a tuple
+        led by one). Its program is named ``engine_<name>``, so that a
+        profiler trace tells the engine's programs apart."""
         if name not in self._compiled:
-            self._compiled[name] = jax.jit(fn, donate_argnums=donate)
+            def program(*args):
+                return fn(*args)
+            program.__name__ = program.__qualname__ = "engine_" + (
+                name[0] if isinstance(name, tuple) else name)
+            self._compiled[name] = jax.jit(program, donate_argnums=donate)
         return self._compiled[name]
 
     # -- expert streaming ----------------------------------------------
@@ -690,7 +693,6 @@ class AdaptiveServingEngine:
         li, ei = key[0], key[1]
         blob = self._host_store.get((li, ei))
         if blob is None:
-            t0 = time.perf_counter()
             moe_p = self.params_train["layers"]["moe"]
             w = {k: np.asarray(moe_p[k][li, ei])
                  for k in ("w_gate", "w_up", "w_down")}
@@ -706,11 +708,6 @@ class AdaptiveServingEngine:
             else:
                 blob = w
             self._host_store[(li, ei)] = blob
-            # host-side staging (extraction + on-the-fly quantization) is
-            # real request-latency but neither decode nor transfer time;
-            # locked: async transfer workers run this loader concurrently
-            with self._stage_lock:
-                self.metrics["stage_s"] += time.perf_counter() - t0
         return blob
 
     def _stream_experts(self, route_ids: np.ndarray, rows: List[int]):
@@ -786,8 +783,9 @@ class AdaptiveServingEngine:
 
         Exposed-time semantics: ``transfer_exposed_s`` is BLOCKED
         WALL-CLOCK, so on a cold host store it also covers the demand
-        fetch's host-side staging (extraction + quantization) that the
-        sync path books under ``stage_s`` — exposed can then exceed the
+        fetch's host-side staging (extraction + quantization), which the
+        sync path counts in no metric (only its ``engine.stream_experts``
+        span holds it) — exposed can then exceed the
         device-transfer counters and ``transfer_overlapped_s`` clamps to
         0. The host store is warm after first touch per (expert, plan),
         so at steady state exposed converges to true transfer waits;
@@ -876,58 +874,76 @@ class AdaptiveServingEngine:
             ps = self.kv_meta.page_size
             sb = min(-(-s // ps) * ps, self.window)
             sb = max(sb, s)           # window may not be a page multiple
-            self.kv_alloc.ensure_prefix(slot, min(s, self.window))
-            fn = self._jit(("prefill_slot_paged", sb), functools.partial(
-                self.model.paged_prefill_into_slot, window=self.window))
         else:
             sb = _bucket(s, hi=self.window)
-            fn = self._jit(("prefill_slot", sb),
-                           self.model.prefill_into_slot)
-        toks = np.zeros((1, sb), np.int32)
-        pos = np.full((1, sb), -1, np.int32)
-        toks[0, :s] = req.prompt
-        pos[0, :s] = np.arange(s)
-        t0 = time.perf_counter()
-        if self.paged:
-            logits, self.kv_pool = fn(
-                self._serve_params, self.kv_pool,
-                jnp.asarray(self.kv_alloc.table[slot]),
-                jnp.asarray(toks), jnp.asarray(pos), jnp.int32(s - 1))
-        else:
-            logits, self.cache = fn(self._serve_params, self.cache,
-                                    jnp.asarray(toks), jnp.asarray(pos),
-                                    jnp.int32(slot), jnp.int32(s - 1))
-        jax.block_until_ready(logits)
-        self.metrics["prefill_s"] += time.perf_counter() - t0
-        self._key, sub = jax.random.split(self._key)
-        temp, top_k = self._sampling_of(req, temperature)
-        tok = int(sample(logits, key=sub, temperature=temp, top_k=top_k,
-                         vocab_size=self.cfg.vocab_size)[0])
-        now = time.perf_counter()
-        req.out_tokens.append(tok)
-        req.t_first = now
-        self.metrics["tokens_generated"] += 1
-        st = self.scheduler.slots[slot]
-        st.last_token = tok
-        if req.done():                      # max_new_tokens == 1
+        with span(self.metrics, "engine.prefill", rid=req.rid, slot=slot,
+                  tokens=s, bucket=sb):
+            self.metrics["prefill_calls"] += 1
+            self.metrics["prefill_tokens"] += s
+            self.metrics["prefill_padded_tokens"] += sb
+            if self.paged:
+                self.kv_alloc.ensure_prefix(slot, min(s, self.window))
+                fn = self._jit(("prefill_paged", sb), functools.partial(
+                    self.model.paged_prefill_into_slot, window=self.window))
+            else:
+                fn = self._jit(("prefill", sb), self.model.prefill_into_slot)
+            toks = np.zeros((1, sb), np.int32)
+            pos = np.full((1, sb), -1, np.int32)
+            toks[0, :s] = req.prompt
+            pos[0, :s] = np.arange(s)
+            # prefill_s: the jitted call and its block_until_ready
+            with span(self.metrics, "engine.prefill.dispatch",
+                      key="prefill_s"):
+                if self.paged:
+                    logits, self.kv_pool = fn(
+                        self._serve_params, self.kv_pool,
+                        jnp.asarray(self.kv_alloc.table[slot]),
+                        jnp.asarray(toks), jnp.asarray(pos),
+                        jnp.int32(s - 1))
+                else:
+                    logits, self.cache = fn(
+                        self._serve_params, self.cache, jnp.asarray(toks),
+                        jnp.asarray(pos), jnp.int32(slot), jnp.int32(s - 1))
+            with span(self.metrics, "engine.prefill.wait", key="prefill_s"):
+                jax.block_until_ready(logits)
+            with span(self.metrics, "engine.sample", key="sample_s"):
+                self._key, sub = jax.random.split(self._key)
+                temp, top_k = self._sampling_of(req, temperature)
+                tok = int(sample(logits, key=sub, temperature=temp,
+                                 top_k=top_k,
+                                 vocab_size=self.cfg.vocab_size)[0])
+            now = time.perf_counter()
+            req.out_tokens.append(tok)
+            req.t_first = now
+            self.metrics["tokens_generated"] += 1
+            st = self.scheduler.slots[slot]
+            st.last_token = tok
+            if req.done():                      # max_new_tokens == 1
+                self._retire(slot, now)
+                return req.rid
+        return None
+
+    def _retire(self, slot: int, now: float) -> None:
+        """Retire the request in ``slot`` and release its KV."""
+        with span(self.metrics, "engine.retire",
+                  rid=self.scheduler.slots[slot].req.rid):
             self.scheduler.retire(slot, now=now)
             self._release_slot_kv(slot)
-            return req.rid
-        return None
 
     def _release_slot_kv(self, slot: int):
         """Retire a slot's KV: paged -> free its pages (tags invalidated
         on device before reuse); slot cache -> invalidate the row."""
-        if self.paged:
-            freed = self.kv_alloc.free_slot(slot)
-            buf = np.zeros(self.kv_meta.chunks_per_slot, np.int32)
-            buf[:len(freed)] = freed
-            self.kv_pool = self._jit(
-                "paged_reset", self.model.paged_reset_pages)(
-                    self.kv_pool, jnp.asarray(buf))
-        else:
-            self.cache = self._jit("reset_slot", self.model.reset_slot)(
-                self.cache, jnp.int32(slot))
+        with span(self.metrics, "engine.kv_release", key="kv_release_s"):
+            if self.paged:
+                freed = self.kv_alloc.free_slot(slot)
+                buf = np.zeros(self.kv_meta.chunks_per_slot, np.int32)
+                buf[:len(freed)] = freed
+                self.kv_pool = self._jit(
+                    "paged_reset", self.model.paged_reset_pages)(
+                        self.kv_pool, jnp.asarray(buf))
+            else:
+                self.cache = self._jit("reset_slot", self.model.reset_slot)(
+                    self.cache, jnp.int32(slot))
 
     def _update_kv_metrics(self, active):
         """Per-iteration KV padding accounting (DESIGN.md §13)."""
@@ -1063,52 +1079,52 @@ class AdaptiveServingEngine:
 
         self._key, k_draft, k_verify = jax.random.split(self._key, 3)
         u_draft = u_acc = u_res = None
-        t0 = time.perf_counter()
-        # -- draft pass: up to K single-token steps at the lowest rung --
-        draft_params = self._draft_serve_params()
-        drafts: Dict[int, List[int]] = {i: [] for i, _ in active}
-        q_rows: Dict[int, List[np.ndarray]] = {i: [] for i, _ in active}
-        prev_tok = {i: st.last_token for i, st in active}
-        for t in range(max(depth.values(), default=0)):
-            toks = np.zeros((B, 1), np.int32)
-            pos = np.full((B, 1), -1, np.int32)
-            rows = [i for i, st in active if depth[i] > t]
+        with span(self.metrics, "engine.decode", key="decode_s",
+                  rows=len(active)):
+            # -- draft pass: up to K single-token steps at the lowest rung
+            draft_params = self._draft_serve_params()
+            drafts: Dict[int, List[int]] = {i: [] for i, _ in active}
+            q_rows: Dict[int, List[np.ndarray]] = {i: [] for i, _ in active}
+            prev_tok = {i: st.last_token for i, st in active}
+            for t in range(max(depth.values(), default=0)):
+                toks = np.zeros((B, 1), np.int32)
+                pos = np.full((B, 1), -1, np.int32)
+                rows = [i for i, st in active if depth[i] > t]
+                for i, st in active:
+                    if depth[i] > t:
+                        toks[i, 0] = prev_tok[i]
+                        pos[i, 0] = st.position + t
+                logits, _ = run_step(draft_params, toks, pos)
+                lg = np.asarray(logits)[:, 0]
+                for i in rows:
+                    temp, top_k = self._sampling_of(
+                        self.scheduler.slots[i].req, temperature)
+                    if temp <= 0.0:
+                        tok = self._greedy_np(lg[i])
+                    else:
+                        if u_draft is None:
+                            u_draft = np.asarray(jax.random.uniform(
+                                k_draft, (max(K, 1), B)))
+                        q = self._probs_np(lg[i], temp, top_k)
+                        cdf = np.cumsum(q)
+                        tok = int(min(np.searchsorted(
+                            cdf, float(u_draft[t, i]), side="right"),
+                            len(cdf) - 1))
+                        q_rows[i].append(q)
+                    drafts[i].append(tok)
+                    prev_tok[i] = tok
+            # -- batched verify at the serving plan (exact) -------------
+            toks = np.zeros((B, S), np.int32)
+            pos = np.full((B, S), -1, np.int32)
             for i, st in active:
-                if depth[i] > t:
-                    toks[i, 0] = prev_tok[i]
-                    pos[i, 0] = st.position + t
-            logits, _ = run_step(draft_params, toks, pos)
-            lg = np.asarray(logits)[:, 0]
-            for i in rows:
-                temp, top_k = self._sampling_of(
-                    self.scheduler.slots[i].req, temperature)
-                if temp <= 0.0:
-                    tok = self._greedy_np(lg[i])
-                else:
-                    if u_draft is None:
-                        u_draft = np.asarray(jax.random.uniform(
-                            k_draft, (max(K, 1), B)))
-                    q = self._probs_np(lg[i], temp, top_k)
-                    cdf = np.cumsum(q)
-                    tok = int(min(np.searchsorted(
-                        cdf, float(u_draft[t, i]), side="right"),
-                        len(cdf) - 1))
-                    q_rows[i].append(q)
-                drafts[i].append(tok)
-                prev_tok[i] = tok
-        # -- batched verify at the serving plan (exact) -----------------
-        toks = np.zeros((B, S), np.int32)
-        pos = np.full((B, S), -1, np.int32)
-        for i, st in active:
-            toks[i, 0] = st.last_token
-            pos[i, 0] = st.position
-            for j, d in enumerate(drafts[i]):
-                toks[i, j + 1] = d
-                pos[i, j + 1] = st.position + j + 1
-        logits, route_ids = run_step(self._serve_params, toks, pos)
-        jax.block_until_ready(logits)
-        lg = np.asarray(logits)                       # (B, S, V)
-        self.metrics["decode_s"] += time.perf_counter() - t0
+                toks[i, 0] = st.last_token
+                pos[i, 0] = st.position
+                for j, d in enumerate(drafts[i]):
+                    toks[i, j + 1] = d
+                    pos[i, j + 1] = st.position + j + 1
+            logits, route_ids = run_step(self._serve_params, toks, pos)
+            jax.block_until_ready(logits)
+            lg = np.asarray(logits)                       # (B, S, V)
         # only the verify's routes feed the expert stream / histogram:
         # the draft banks are fully resident by construction
         rows = [i * S + j for i, _ in active
@@ -1166,6 +1182,7 @@ class AdaptiveServingEngine:
                         self.cache, jnp.asarray(keep))
         self._update_kv_metrics(active)
         self.metrics["iterations"] += 1
+        self.metrics["decode_rows"] += len(active)
         if self.metrics["spec_proposed"]:
             self.metrics["acceptance_rate"] = \
                 self.metrics["spec_accepted"] \
@@ -1178,8 +1195,7 @@ class AdaptiveServingEngine:
             st.position += len(emitted[i])
             st.last_token = int(emitted[i][-1])
             if st.req.done():
-                self.scheduler.retire(i, now=now)
-                self._release_slot_kv(i)
+                self._retire(i, now)
                 retired.append(st.req.rid)
             elif self.paged and depth[i]:
                 # free pages holding only rejected tokens (their tags
@@ -1197,9 +1213,16 @@ class AdaptiveServingEngine:
         if self._plan_result is None:
             raise RuntimeError(
                 "no active plan: apply_target() or configure() first")
+        with span(self.metrics, "engine.iteration"):
+            return self._iteration(admit, temperature)
+
+    def _iteration(self, admit: bool, temperature: float) -> List[int]:
+        m = self.metrics
         retired: List[int] = []
         if admit:
-            for slot, req in self.scheduler.admit():
+            with span(m, "engine.admit"):
+                joined = self.scheduler.admit()
+            for slot, req in joined:
                 rid = self._prefill_slot(slot, req, temperature)
                 if rid is not None:
                     retired.append(rid)
@@ -1219,75 +1242,82 @@ class AdaptiveServingEngine:
         if self.paged:
             # map the chunk each active slot's ring write lands in BEFORE
             # the jitted step (host-side page table, device-side pool)
-            for i, st in active:
-                self.kv_alloc.ensure_index(i, st.position % self.window)
+            with span(m, "engine.kv_map"):
+                for i, st in active:
+                    self.kv_alloc.ensure_index(i, st.position % self.window)
         route_ids = None
-        if self._pipeline:
-            # overlap mode: decode through the per-layer lookahead
-            # pipeline; expert streaming happens inside (DESIGN.md §12)
-            logits = self._decode_pipelined(toks, pos,
-                                            [i for i, _ in active])
-        elif self.paged:
-            decode = self._jit("decode_paged", functools.partial(
-                self.model.paged_decode_step_routed, window=self.window))
-            t0 = time.perf_counter()
-            logits, self.kv_pool, route_ids = decode(
-                self._serve_params, self.kv_pool,
-                jnp.asarray(self.kv_alloc.table), jnp.asarray(toks),
-                jnp.asarray(pos))
-            jax.block_until_ready(logits)
-            self.metrics["decode_s"] += time.perf_counter() - t0
-        else:
-            decode = self._jit("decode", self.model.decode_step_routed)
-            t0 = time.perf_counter()
-            logits, self.cache, route_ids = decode(
-                self._serve_params, self.cache, jnp.asarray(toks),
-                jnp.asarray(pos))
-            jax.block_until_ready(logits)
-            self.metrics["decode_s"] += time.perf_counter() - t0
+        with span(m, "engine.decode", rows=len(active)):
+            if self._pipeline:
+                # overlap mode: decode through the per-layer lookahead
+                # pipeline; expert streaming happens inside (DESIGN.md §12)
+                logits = self._decode_pipelined(toks, pos,
+                                                [i for i, _ in active])
+            else:
+                if self.paged:
+                    decode = self._jit("decode_paged", functools.partial(
+                        self.model.paged_decode_step_routed,
+                        window=self.window))
+                else:
+                    decode = self._jit("decode", self.model.decode_step_routed)
+                # decode_s: the jitted call and its block_until_ready
+                with span(m, "engine.decode.dispatch", key="decode_s"):
+                    if self.paged:
+                        logits, self.kv_pool, route_ids = decode(
+                            self._serve_params, self.kv_pool,
+                            jnp.asarray(self.kv_alloc.table),
+                            jnp.asarray(toks), jnp.asarray(pos))
+                    else:
+                        logits, self.cache, route_ids = decode(
+                            self._serve_params, self.cache,
+                            jnp.asarray(toks), jnp.asarray(pos))
+                with span(m, "engine.decode.wait", key="decode_s"):
+                    jax.block_until_ready(logits)
         self._update_kv_metrics(active)
-        self.metrics["iterations"] += 1
-        self._key, sub = jax.random.split(self._key)
-        if any(st.req.sampling is not None for _, st in active):
-            # heterogeneous per-request SamplingParams: sample row-wise
-            # (the batched path below stays bit-identical when no request
-            # carries its own parameters)
-            new_toks = np.zeros((self.max_slots,), np.int32)
-            keys = jax.random.split(sub, self.max_slots)
-            for i, st in active:
-                temp, top_k = self._sampling_of(st.req, temperature)
-                new_toks[i] = int(sample(
-                    logits[i:i + 1], key=keys[i], temperature=temp,
-                    top_k=top_k, vocab_size=self.cfg.vocab_size)[0])
-        else:
-            new_toks = np.asarray(sample(logits, key=sub,
-                                         temperature=temperature,
-                                         vocab_size=self.cfg.vocab_size))
-        if route_ids is not None:     # sync path (pipelined streams inline)
-            self._stream_experts(np.asarray(route_ids),
-                                 [i for i, _ in active])
-        # analytical cross-check: expected UNIQUE streamed bytes of this
-        # iteration under uniform routing. n_active rows draw
-        # d = top_k * n_active experts per layer; each off-device expert
-        # streams iff drawn at least once, so the per-token expectation
-        # (miss_bytes_per_tok = sum_offdev size/E * top_k) is rescaled by
-        # E * (1 - (1-1/E)^d) / top_k. Measured below this estimate then
-        # isolates CROSS-iteration locality (the LRU's contribution).
-        e = self.cfg.moe.num_experts
-        d = self.cfg.moe.top_k * len(active)
-        uniq = e * (1.0 - (1.0 - 1.0 / e) ** d)
-        self.metrics["transfer_s_est"] += \
-            self._miss_bytes_per_tok * uniq / self.cfg.moe.top_k \
-            / self.hw.host_link_bw
+        m["iterations"] += 1
+        m["decode_rows"] += len(active)
+        with span(m, "engine.sample", key="sample_s"):
+            self._key, sub = jax.random.split(self._key)
+            if any(st.req.sampling is not None for _, st in active):
+                # heterogeneous per-request SamplingParams: sample
+                # row-wise (the batched path below stays bit-identical
+                # when no request carries its own parameters)
+                new_toks = np.zeros((self.max_slots,), np.int32)
+                keys = jax.random.split(sub, self.max_slots)
+                for i, st in active:
+                    temp, top_k = self._sampling_of(st.req, temperature)
+                    new_toks[i] = int(sample(
+                        logits[i:i + 1], key=keys[i], temperature=temp,
+                        top_k=top_k, vocab_size=self.cfg.vocab_size)[0])
+            else:
+                new_toks = np.asarray(sample(
+                    logits, key=sub, temperature=temperature,
+                    vocab_size=self.cfg.vocab_size))
+        with span(m, "engine.stream_experts"):
+            if route_ids is not None:  # sync path (pipelined streams inline)
+                self._stream_experts(np.asarray(route_ids),
+                                     [i for i, _ in active])
+            # analytical cross-check: expected UNIQUE streamed bytes of
+            # this iteration under uniform routing. n_active rows draw
+            # d = top_k * n_active experts per layer; each off-device
+            # expert streams iff drawn at least once, so the per-token
+            # expectation (miss_bytes_per_tok = sum_offdev size/E * top_k)
+            # is rescaled by E * (1 - (1-1/E)^d) / top_k. Measured below
+            # this estimate then isolates CROSS-iteration locality (the
+            # LRU's contribution).
+            e = self.cfg.moe.num_experts
+            d = self.cfg.moe.top_k * len(active)
+            uniq = e * (1.0 - (1.0 - 1.0 / e) ** d)
+            m["transfer_s_est"] += \
+                self._miss_bytes_per_tok * uniq / self.cfg.moe.top_k \
+                / self.hw.host_link_bw
         now = time.perf_counter()
         for i, st in active:
             st.req.out_tokens.append(int(new_toks[i]))
-            self.metrics["tokens_generated"] += 1
+            m["tokens_generated"] += 1
             st.position += 1
             st.last_token = int(new_toks[i])
             if st.req.done():
-                self.scheduler.retire(i, now=now)
-                self._release_slot_kv(i)
+                self._retire(i, now)
                 retired.append(st.req.rid)
         return retired
 
@@ -1373,9 +1403,11 @@ class AdaptiveServingEngine:
         """Zero the throughput counters (between benchmark operating
         points); plan/reconfig counters are preserved."""
         for k in ("tokens_generated", "decode_s", "prefill_s",
-                  "transfer_s", "transfer_s_est", "stage_s",
+                  "transfer_s", "transfer_s_est",
                   "prefetch_s", "transfer_exposed_s",
-                  "transfer_overlapped_s",
+                  "transfer_overlapped_s", "sample_s", "kv_release_s",
+                  "prefill_calls", "prefill_tokens",
+                  "prefill_padded_tokens", "decode_rows",
                   "expert_accesses", "expert_fetches", "iterations",
                   "kv_alloc_byte_iters", "kv_used_byte_iters",
                   "spec_proposed", "spec_accepted", "acceptance_rate"):

@@ -36,10 +36,19 @@ ENGINE_METRIC_SCHEMA: Dict[str, Any] = {
     "prefill_s": 0.0,
     "transfer_s": 0.0,
     "transfer_s_est": 0.0,
-    "stage_s": 0.0,
     "prefetch_s": 0.0,
     "transfer_exposed_s": 0.0,
     "transfer_overlapped_s": 0.0,
+    # host-loop parts and batch shape, each from its engine span
+    # (serving/tracing.py): sampling, KV page release; prefills with
+    # their real prompt tokens and the compiled bucket's tokens; decode
+    # rows summed over decode steps
+    "sample_s": 0.0,
+    "kv_release_s": 0.0,
+    "prefill_calls": 0,
+    "prefill_tokens": 0,
+    "prefill_padded_tokens": 0,
+    "decode_rows": 0,
     # reconfiguration / drains (DESIGN.md §10.3)
     "reconfig_s": 0.0,
     "reconfigs": 0,
