@@ -16,7 +16,11 @@ capacity-bounded tokens-per-expert buffer the MoE dispatch packs
 weights/scales through its BlockSpec and dequantizes **in VMEM** right
 before the MXU dot with the per-expert kernel's own ``dequant_tile``
 (``q4_matmul``) — per-tile arithmetic is identical, so the grouped q4/q8
-results are bit-exact against the per-expert loop (tested). The bf16 bank
+results are bit-exact against the per-expert loop (tested). For int4 that
+tile's K order is ``[even | odd]`` within each BK block (no sublane
+interleave in the kernel), and x's K is reordered to match before the
+launch by the same helper the per-expert kernel uses
+(``q4_matmul.even_odd_k``). The bf16 bank
 gets the same grouped layout without the dequant (f32 accumulation, so
 parity with the jnp einsum is allclose, not bitwise).
 
@@ -39,11 +43,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.q4_matmul import dequant_tile
+from repro.kernels.q4_matmul import dequant_tile, even_odd_k
 
 
 def _grouped_q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
-                      group_size: int, block_k: int, bits: int):
+                      group_size: int, bits: int):
     """One (BM, BN) output tile of one expert group; K streamed over grid
     axis 3. All refs carry a leading group-block of 1."""
     k = pl.program_id(3)
@@ -53,7 +57,7 @@ def _grouped_q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     w_f = dequant_tile(wq_ref[0], sc_ref[0], bits=bits,
-                       group_size=group_size, block_k=block_k)
+                       group_size=group_size)
     acc_ref[...] += jax.lax.dot(
         x_ref[0].astype(jnp.float32), w_f,
         preferred_element_type=jnp.float32)
@@ -124,11 +128,12 @@ def grouped_quantized_matmul(
 
     grid = (g, c // block_m, n // block_n, kdim // block_k)
     w_rows = block_k // 2 if bits == 4 else block_k
+    if bits == 4:
+        x = even_odd_k(x, block_k)
 
     return pl.pallas_call(
         functools.partial(_grouped_q_kernel, nk=grid[3],
-                          group_size=group_size, block_k=block_k,
-                          bits=bits),
+                          group_size=group_size, bits=bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_m, block_k),

@@ -20,6 +20,11 @@ Design (DESIGN.md §2, hardware adaptation):
     under v5e's scoped VMEM even double-buffered.
   * ``dot(int8-ish bf16 values)`` uses preferred_element_type=f32 so the MXU
     accumulates in f32.
+  * int4 K order: a dequantized int4 tile holds the low nibbles' rows (even
+    K) above the high nibbles' rows (odd K), ``[even | odd]`` within each
+    BK block, because interleaving them back into K order costs a sublane
+    shuffle per tile. ``quantized_matmul`` reorders x's K to match before
+    the ``pallas_call`` (``even_odd_k``); the packing is unchanged.
 
 The pure-jnp oracle lives in ``repro.kernels.ref``; jit'd public wrappers in
 ``repro.kernels.ops``.
@@ -34,27 +39,48 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def dequant_tile(wq, sc, *, bits: int, group_size: int, block_k: int):
+def dequant_tile(wq, sc, *, bits: int, group_size: int):
     """Unpack + scale one (BK, BN) weight tile in VMEM, in f32.
 
     int4 bytes hold K indices (2b, 2b+1) as (low, high) nibbles. They are
     widened to int32 before any mask, shift or subtract: the TPU vector
-    unit has no 8-bit integer ALU, so Mosaic refuses int8 arithmetic."""
+    unit has no 8-bit integer ALU, so Mosaic refuses int8 arithmetic.
+
+    The int4 tile comes out in K order ``[even K | odd K]``: the low
+    nibbles' (BK/2, BN) block, then the high nibbles'. Each half is
+    converted and scaled on its own, byte row ``b`` by scale row
+    ``2b // group_size``, and the halves are joined along sublanes, which
+    costs nothing when aligned. Interleaving them back into K order would
+    shuffle sublanes on every tile; instead the caller hands the kernel x
+    with its K reordered to match (:func:`even_odd_k`)."""
+    sc = sc.astype(jnp.float32)                        # (BK/G, BN)
     if bits == 4:
         w = wq.astype(jnp.int32)                       # (BK//2, BN)
-        lo = (w & 0xF) - 8
-        hi = (w >> 4) - 8
-        w_int = jnp.stack([lo, hi], axis=1).reshape(block_k, w.shape[1])
-    else:
-        w_int = wq                                     # (BK, BN) int8
-    sc = sc.astype(jnp.float32)                        # (BK/G, BN)
+        halves = [_scale(half, sc, group_size // 2)
+                  for half in ((w & 0xF) - 8, (w >> 4) - 8)]
+        return jnp.concatenate(halves, axis=0)
+    return _scale(wq, sc, group_size)                  # (BK, BN) int8
+
+
+def _scale(w_int, sc, group_rows: int):
+    """f32 ``w_int`` with row ``r`` scaled by ``sc[r // group_rows]``."""
+    rows = w_int.shape[0]
     w_f = w_int.astype(jnp.float32).reshape(
-        block_k // group_size, group_size, -1) * sc[:, None, :]
-    return w_f.reshape(block_k, -1)
+        rows // group_rows, group_rows, -1) * sc[:, None, :]
+    return w_f.reshape(rows, -1)
+
+
+def even_odd_k(x: jax.Array, block_k: int) -> jax.Array:
+    """Reorder x's last axis (K) to the int4 tile's order: within each BK
+    block, the even K indices, then the odd ones (:func:`dequant_tile`).
+    One pass over x per call, outside the kernel."""
+    *lead, kdim = x.shape
+    return x.reshape(*lead, kdim // block_k, block_k // 2, 2).swapaxes(
+        -1, -2).reshape(*lead, kdim)
 
 
 def _q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
-              group_size: int, block_k: int, bits: int):
+              group_size: int, bits: int):
     """One (BM, BN) output tile; K streamed over grid axis 2."""
     k = pl.program_id(2)
 
@@ -63,7 +89,7 @@ def _q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     w_f = dequant_tile(wq_ref[...], sc_ref[...], bits=bits,
-                       group_size=group_size, block_k=block_k)
+                       group_size=group_size)
     acc_ref[...] += jax.lax.dot(
         x_ref[...].astype(jnp.float32), w_f,
         preferred_element_type=jnp.float32)
@@ -114,10 +140,12 @@ def quantized_matmul(
 
     grid = (m // block_m, n // block_n, kdim // block_k)
     w_rows = block_k // 2 if bits == 4 else block_k
+    if bits == 4:
+        x = even_odd_k(x, block_k)
 
     return pl.pallas_call(
         functools.partial(_q_kernel, nk=grid[2], group_size=group_size,
-                          block_k=block_k, bits=bits),
+                          bits=bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),

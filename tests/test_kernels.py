@@ -13,6 +13,7 @@ from repro.core.quantization import QTensor, quantize
 from repro.kernels import (
     expert_matmul_ref, q_expert_matmul, q_matmul, quantized_matmul_ref,
 )
+from repro.kernels.grouped_matmul import grouped_quantized_matmul
 from repro.kernels.q4_matmul import quantized_matmul
 
 
@@ -243,3 +244,36 @@ class TestKernelNumerics:
                                out_dtype=jnp.float32, interpret=True)
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(x, np.float32) @ q.astype(np.float32))
+
+    @pytest.mark.parametrize("block_k", [128, 512])
+    @pytest.mark.parametrize("group", [32, 64, 128])
+    @pytest.mark.parametrize("grouped", [False, True])
+    def test_q4_tight_parity_distinct_scales(self, grouped, group, block_k):
+        """int4 against float32 dequantize-then-matmul at rtol 1e-5, with a
+        different scale for every (group, column) and K over two BK tiles:
+        a wrong in-tile K order or a wrong scale row for either nibble
+        half is far outside this tolerance (the oracle tests' 5e-2 is
+        not)."""
+        from repro.core.quantization import pack_int4
+        g, m, k, n = 2, 16, 2 * block_k, 128
+        rng = np.random.default_rng(group + block_k)
+        q = rng.integers(-8, 8, (g, k, n)).astype(np.int8)
+        scales = (0.5 + rng.permutation(g * (k // group) * n)
+                  .reshape(g, k // group, n) / (g * (k // group) * n)
+                  ).astype(np.float32)
+        x = jnp.asarray(rng.standard_normal((g, m, k)), jnp.bfloat16)
+        packed = pack_int4(jnp.asarray(q))
+        w = (q.astype(np.float32).reshape(g, k // group, group, n)
+             * scales[:, :, None, :]).reshape(g, k, n)
+        ref = np.einsum("gmk,gkn->gmn", np.asarray(x, np.float64), w)
+        kw = dict(bits=4, group_size=group, block_m=m, block_n=n,
+                  block_k=block_k, out_dtype=jnp.float32, interpret=True)
+        if grouped:
+            out = grouped_quantized_matmul(x, packed, jnp.asarray(scales),
+                                           **kw)
+        else:
+            out = jnp.stack([quantized_matmul(x[e], packed[e],
+                                              jnp.asarray(scales[e]), **kw)
+                             for e in range(g)])
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5,
+                                   atol=1e-5 * np.abs(ref).max())

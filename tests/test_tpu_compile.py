@@ -67,22 +67,36 @@ def _assert_kernel(fn, *args, name):
 PROJ = {"up": (D_MODEL, D_FF), "down": (D_FF, D_MODEL)}
 #: decode (M=8 rows per expert group) and prefill (M=128)
 ROWS = [8, 128]
+#: rows per expert group of a 64-row decode batch
+DECODE_BATCH_ROWS = 64
 
 
-@pytest.mark.parametrize("m", ROWS)
-@pytest.mark.parametrize("proj", sorted(PROJ))
-@pytest.mark.parametrize("bits", [4, 8])
-def test_grouped_q_kernel_compiles(one_chip, bits, proj, m):
+def _assert_grouped_q_kernel(sharding, bits, proj, m):
     k, n = PROJ[proj]
-    q, sc = _q_args(one_chip, (EXPERTS,), k, n, bits)
+    q, sc = _q_args(sharding, (EXPERTS,), k, n, bits)
     x = jax.ShapeDtypeStruct((EXPERTS, m, k), jnp.bfloat16,
-                             sharding=one_chip)
+                             sharding=sharding)
 
     def fn(x, q, sc):
         return ops.grouped_q_matmul(
             x, QTensor(q=q, scales=sc, bits=bits, group_size=GROUP),
             interpret=False)
     _assert_kernel(fn, x, q, sc, name=f"grouped_q{bits}_matmul")
+
+
+@pytest.mark.parametrize("m", ROWS)
+@pytest.mark.parametrize("proj", sorted(PROJ))
+@pytest.mark.parametrize("bits", [4, 8])
+def test_grouped_q_kernel_compiles(one_chip, bits, proj, m):
+    _assert_grouped_q_kernel(one_chip, bits, proj, m)
+
+
+@pytest.mark.parametrize("proj", sorted(PROJ))
+def test_grouped_q4_kernel_compiles_at_decode_batch_rows(one_chip, proj):
+    """The int4 kernel at the 64 rows per expert group of a 64-row decode
+    batch (top-2 of 8, capacity factor 4): x reordered to the tile's
+    [even K | odd K] order and the nibble halves joined along sublanes."""
+    _assert_grouped_q_kernel(one_chip, 4, proj, DECODE_BATCH_ROWS)
 
 
 @pytest.mark.parametrize("m", ROWS)
