@@ -129,8 +129,12 @@ def run(cell, *, seed: int, seconds: float, trace: bool = False,
     trace_sum = None
     if trace:
         jax.profiler.stop_trace()
-        trace_sum = TR.reduce_dir(tdir, pk, c)
+        t = time.perf_counter()
+        trace_sum = TR.reduce_dir(tdir, pk, c, cell.kernels())
         shutil.rmtree(tdir, ignore_errors=True)
+        log(f"trace reduced in {time.perf_counter() - t!r} s; idle s by "
+            f"span {json.dumps(trace_sum['idle_by_span'])}; programs "
+            f"{json.dumps(trace_sum['modules'])}")
     stats = dev.memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
     log(f"peak_bytes_in_use after the window {peak}; bytes_limit "

@@ -1,8 +1,11 @@
 """Set-up and the measured window. This is the one module of the
-benchmark that imports the program under test, and it uses the program
-only as a served path: ``build_engine``, ``apply_frontier_point``,
+harness that imports the program under test (an architecture file's
+``program_config`` builds the program's configuration), and it uses the
+program only as a served path: ``build_engine``, ``apply_frontier_point``,
 ``submit_request``, ``run_iteration`` and ``enable_compile_cache``, plus
-the scheduler's request records for their timestamps.
+the scheduler's request records for their timestamps and the engine's
+counters. Whatever depends on the architecture it asks the
+configuration's architecture file (``bench/archs/<arch>.py``).
 """
 from __future__ import annotations
 
@@ -16,9 +19,7 @@ import jax
 import numpy as np
 
 from benchlib import traffic as T
-from benchlib import weights as W
 
-from repro.configs import get_config
 from repro.core.precision_plan import HOST
 from repro.serving.api import EngineConfig, ServeRequest, build_engine
 
@@ -26,27 +27,12 @@ from repro.serving.api import EngineConfig, ServeRequest, build_engine
 ALL_RESIDENT_BUDGET = float(1 << 50)
 
 
-def program_config(c: dict):
-    """The program's ModelConfig for configuration file ``c``; refuses a
-    program whose parameter layout differs from the benchmark's."""
-    base = get_config(c["program_arch"])
-    plan = c["plan"]
-    cfg = base.replace(
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        attention=dataclasses.replace(
-            base.attention, num_heads=c["num_attention_heads"],
-            num_kv_heads=c["num_key_value_heads"],
-            head_dim=c["head_dim"], sliding_window=c["sliding_window"],
-            rope_theta=c["rope_theta"]),
-        moe=dataclasses.replace(
-            base.moe, num_experts=c["num_local_experts"],
-            top_k=c["num_experts_per_tok"],
-            d_ff_expert=c["intermediate_size"],
-            capacity_factor=c["moe_capacity_factor"]),
-        mop=dataclasses.replace(base.mop, ladder=tuple(plan["ladder"]),
-                                group_size=plan["group_size"]))
-    ours = W.full_shapes(c)
+def program_config(arch, c: dict):
+    """The program's ModelConfig for configuration file ``c``, from its
+    architecture file; refuses a program whose parameter layout differs
+    from the tree the architecture makes."""
+    cfg = arch.program_config(c)
+    ours = arch.full_shapes(c)
     theirs = {n: tuple(s) for n, s in cfg.param_shapes()}
     if ours != theirs:
         raise RuntimeError(f"the program's parameter layout {theirs} is "
@@ -54,18 +40,18 @@ def program_config(c: dict):
     return cfg
 
 
-def pinned_point(engine, c: dict):
+def pinned_point(engine, c: dict, experts: int):
     """A frontier point with the configuration's per-rung counts and a
-    budget that holds every expert on the device. The frontier keeps no
-    all-resident point with three rungs, so the point is built from one
-    of its points with these counts in place of that point's."""
+    budget that holds all ``experts`` (layer, expert) pairs on the
+    device. The frontier keeps no all-resident point with three rungs, so
+    the point is built from one of its points with these counts in place
+    of that point's."""
     plan = c["plan"]
     counts = tuple(int(x) for x in plan["counts_per_rung"])
     base = engine.frontier.points[0]
     q = sum(n for b, n in zip(plan["ladder"], counts) if b < 16)
-    total = c["num_hidden_layers"] * c["num_local_experts"]
     return dataclasses.replace(
-        base, num_q_experts=q, resident_experts=total,
+        base, num_q_experts=q, resident_experts=experts,
         counts_per_rung=counts, peer_experts=0,
         qos=dataclasses.replace(base.qos,
                                 device_bytes=ALL_RESIDENT_BUDGET))
@@ -106,7 +92,9 @@ class Window:
     t0: float
     end: float
     records: List[Record]
-    counters: Dict[str, float]       # engine counter deltas in the window
+    # engine counter deltas in the window; None for a counter the
+    # program does not keep
+    counters: Dict[str, Optional[float]]
     host_s: float                    # run_iteration wall minus its spans
     iterations: int
     prefill_tokens: int
@@ -125,9 +113,10 @@ class Setup:
                  engine_hook: Optional[Callable] = None):
         c, mix = cell.config, cell.traffic
         self.cell, self.seed = cell, seed
-        self.cfg = program_config(c)
+        self.arch = cell.arch()
+        self.cfg = program_config(self.arch, c)
         t = time.perf_counter()
-        self.params = W.make_params(c, seed)
+        self.params = self.arch.make_params(c, seed)
         jax.block_until_ready(self.params)
         self.times = {"weights_s": time.perf_counter() - t}
         t = time.perf_counter()
@@ -137,7 +126,8 @@ class Setup:
             use_kernel=None, paged_kv=True))
         if engine_hook is not None:
             engine_hook(self.engine)
-        self.engine.apply_frontier_point(pinned_point(self.engine, c))
+        self.engine.apply_frontier_point(pinned_point(
+            self.engine, c, self.arch.expert_pairs(c)))
         check_plan(self.engine, c)
         self.times["engine_s"] = time.perf_counter() - t
         t = time.perf_counter()
@@ -167,17 +157,28 @@ class Setup:
                 self.engine.run_iteration(temperature=0.0)
 
     def device_bytes(self) -> Dict[str, int]:
-        """The reckoning of what the engine holds on the device."""
-        def nbytes(tree):
-            return int(sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)))
-        serve = self.engine.serve_params
-        return {"train_copy": nbytes(self.params),
-                "banks": nbytes(serve["layers"]["moe"]["banks"]),
-                "kv_pool": nbytes(self.engine.kv_pool)}
+        """The reckoning of what the engine holds: the train copy by
+        where each leaf lives, then the architecture's device parts."""
+        out = {"train_copy_device": 0, "train_copy_host": 0}
+        for x in jax.tree_util.tree_leaves(self.params):
+            out["train_copy_host" if on_host(x) else
+                "train_copy_device"] += int(x.nbytes)
+        for name, tree in self.arch.device_parts(self.engine).items():
+            out[name] = int(sum(x.nbytes for x in
+                                jax.tree_util.tree_leaves(tree)))
+        return out
 
     def close(self) -> None:
         self.engine.close()
         self.engine = self.params = None
+
+
+def on_host(x) -> bool:
+    """Whether an array lives in host memory: a NumPy array, or a JAX
+    array in one of the host memory kinds."""
+    if not isinstance(x, jax.Array):
+        return True
+    return "host" in (x.sharding.memory_kind or "")
 
 
 #: JAX's events for a traced function, an XLA compilation and a program
@@ -219,10 +220,20 @@ class _Monitor:
 _MONITOR: Optional[_Monitor] = None
 
 
-def _stats(engine) -> Dict[str, float]:
+#: the engine counters the window reads; a program without one reads None
+COUNTERS = ("prefill_s", "decode_s", "iterations", "tokens_generated",
+            "sample_s", "kv_release_s", "prefill_calls", "prefill_tokens",
+            "prefill_padded_tokens", "decode_rows")
+
+
+def _stats(engine) -> Dict[str, Optional[float]]:
     m = engine.metrics
-    return {k: float(m[k]) for k in ("prefill_s", "decode_s", "iterations",
-                                     "tokens_generated")}
+    return {k: None if m.get(k) is None else float(m[k]) for k in COUNTERS}
+
+
+def _span_seconds(engine) -> tuple:
+    m = engine.metrics
+    return m["prefill_s"], m["decode_s"]
 
 
 def run_window(setup: Setup, seconds: float, *, trace_annotation=None,
@@ -256,7 +267,8 @@ def run_window(setup: Setup, seconds: float, *, trace_annotation=None,
         records.append(rec)
         live.append(rec)
 
-    from benchlib.roofline import decode_flops, prefill_flops
+    prefill_flops, decode_flops = (setup.arch.prefill_flops,
+                                   setup.arch.decode_flops)
     s0 = _stats(eng)
     host_s = 0.0
     n_iter = 0
@@ -300,17 +312,16 @@ def run_window(setup: Setup, seconds: float, *, trace_annotation=None,
                 if now >= end:
                     break
                 continue
-            before = _stats(eng)
+            pre0, dec0 = _span_seconds(eng)
             t_it = time.perf_counter()
             with ann("bench.iteration"):
                 eng.run_iteration(temperature=0.0)
             t_done = time.perf_counter()
-            after = _stats(eng)
+            pre1, dec1 = _span_seconds(eng)
             in_window = t_it < end
             if in_window:
                 n_iter += 1
-                d_pre = after["prefill_s"] - before["prefill_s"]
-                d_dec = after["decode_s"] - before["decode_s"]
+                d_pre, d_dec = pre1 - pre0, dec1 - dec0
                 host_s += (t_done - t_it) - d_pre - d_dec
                 its.append((t_it, t_done - t_it, d_pre, d_dec, slept))
             still = []
@@ -336,7 +347,8 @@ def run_window(setup: Setup, seconds: float, *, trace_annotation=None,
     _MONITOR.active = False
     if s_end is None:
         s_end = _stats(eng)
-    counters = {k: s_end[k] - s0[k] for k in s0}
+    counters = {k: None if s0[k] is None or s_end[k] is None
+                else s_end[k] - s0[k] for k in s0}
     return Window(t0=t0, end=end, records=records, counters=counters,
                   host_s=host_s, iterations=n_iter, prefill_tokens=pre_tok,
                   prefill_flops=pre_fl, decode_flops=dec_fl,

@@ -4,8 +4,11 @@ A configuration is the file its entry names; a traffic mix is
 ``<bench>/traffic/<traffic>.json``; a per-layer metric is the reader
 ``<bench>/metrics/<name>.py``, a module with ``read(ctx)`` that returns
 the metric's value, or ``None`` where it finds nothing to read; a
-configuration's plain reference is ``<bench>/references/<reference>.py``,
-named by the configuration file. Adding any of them is adding files.
+configuration's plain reference is ``<bench>/references/<reference>.py``
+and its architecture ``<bench>/archs/<arch>.py``, both named by the
+configuration file; a kernel counter is any ``<bench>/kernels/<name>.py``,
+found by the Pallas names it lists in ``KERNELS``. Adding any of them is
+adding files.
 """
 from __future__ import annotations
 
@@ -30,6 +33,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     bench_dir: Path
+    _arch: object = dataclasses.field(default=None, repr=False)
 
     def reader(self, metric: str) -> Callable:
         return load_module(self.bench_dir / "metrics" / f"{metric}.py").read
@@ -37,6 +41,16 @@ class Cell:
     def reference(self):
         return load_module(self.bench_dir / "references"
                            / f"{self.config['reference']}.py")
+
+    def arch(self):
+        """The configuration's architecture file, loaded once a cell."""
+        if self._arch is None:
+            self._arch = load_module(self.bench_dir / "archs"
+                                     / f"{self.config['arch']}.py")
+        return self._arch
+
+    def kernels(self) -> Dict[str, object]:
+        return kernel_counters(self.bench_dir)
 
 
 def load_module(path: Path):
@@ -47,6 +61,16 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def kernel_counters(bench_dir: Path = BENCH) -> Dict[str, object]:
+    """Kernel name -> the counter under ``<bench_dir>/kernels`` that lists
+    it."""
+    out: Dict[str, object] = {}
+    for path in sorted((bench_dir / "kernels").glob("*.py")):
+        mod = load_module(path)
+        out.update((name, mod) for name in mod.KERNELS)
+    return out
 
 
 def load_benchmark(root: Path = ROOT) -> dict:

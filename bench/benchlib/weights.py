@@ -1,12 +1,15 @@
 """Random weights from ``--seed``, made by the benchmark and not by the
 program, so that the reference can make the same ones again.
 
-Every leaf, layer and expert has a key of its own (``slice_key``), so
-one layer or one expert can be regenerated alone (``leaf_slice``) with
-the values the whole tree holds. The tree has the train layout the
-engine takes (``layers/moe/w_gate`` is ``(L, E, d, f)``, ...), in
-bfloat16, the type it is served in; norm scales are ones; every other
-leaf is normal with standard deviation ``1 / sqrt(fan_in)``.
+An architecture file (``bench/archs/<arch>.py``) states its tree as a
+layout: for every leaf its name, the shape of one slice and the axes
+stacked in front of it, each axis a letter the architecture counts
+(``"L"`` its layers, ``"E"`` its experts, ...). From a layout this module
+makes the tree, its shapes and any one slice. Every leaf, layer and
+expert has a key of its own (``slice_key``), so one slice can be
+regenerated alone with the values the whole tree holds. Leaves are
+bfloat16, the type they are served in; norm scales are ones; every
+other leaf is normal with standard deviation ``1 / sqrt(fan_in)``.
 """
 from __future__ import annotations
 
@@ -14,6 +17,10 @@ from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+
+#: (name, shape of one slice, stacked axes) for every leaf of a tree
+Layout = List[Tuple[str, tuple, str]]
+
 
 def served(c: dict, key: str):
     """What the program serves with: the configuration's stated
@@ -24,34 +31,10 @@ def served(c: dict, key: str):
     return dep[key] if key in dep else c.get(key, published.get(key))
 
 
-def layout(c: dict) -> List[Tuple[str, tuple, str]]:
-    """(name, shape of one slice, axes stacked in front of it) for every
-    leaf: "L" stacks the layers, "LE" the layers and then the experts."""
-    d, f = c["hidden_size"], c["intermediate_size"]
-    h, hkv, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                  c["head_dim"])
-    e, v = c["num_local_experts"], served(c, "vocab_rows")
-    return [
-        ("embed/table", (v, d), ""),
-        ("final_norm/scale", (d,), ""),
-        ("lm_head/table", (v, d), ""),
-        ("layers/attn/wq", (d, h * hd), "L"),
-        ("layers/attn/wk", (d, hkv * hd), "L"),
-        ("layers/attn/wv", (d, hkv * hd), "L"),
-        ("layers/attn/wo", (h * hd, d), "L"),
-        ("layers/attn_norm/scale", (d,), "L"),
-        ("layers/moe/router", (d, e), "L"),
-        ("layers/moe/w_gate", (d, f), "LE"),
-        ("layers/moe/w_up", (d, f), "LE"),
-        ("layers/moe/w_down", (f, d), "LE"),
-        ("layers/ffn_norm/scale", (d,), "L"),
-    ]
-
-
-def full_shapes(c: dict) -> Dict[str, tuple]:
-    n = {"L": c["num_hidden_layers"], "E": c["num_local_experts"]}
-    return {name: tuple(n[a] for a in axes) + shape
-            for name, shape, axes in layout(c)}
+def full_shapes(layout: Layout, counts: Dict[str, int]) -> Dict[str, tuple]:
+    """Every leaf's whole shape, its stacked axes counted by ``counts``."""
+    return {name: tuple(counts[a] for a in axes) + shape
+            for name, shape, axes in layout}
 
 
 def base_key(seed: int) -> jax.Array:
@@ -74,32 +57,34 @@ def _draw(key, name: str, shape) -> jax.Array:
             ).astype(jnp.bfloat16)
 
 
-def make_params(c: dict, seed: int) -> dict:
-    """The whole tree on the default device, in one jitted call."""
-    nl, ne = c["num_hidden_layers"], c["num_local_experts"]
-
+def make_tree(layout: Layout, counts: Dict[str, int], seed: int) -> dict:
+    """The whole tree on the default device, in one jitted call. A leaf
+    stacks at most two axes; the first is a slice key's ``layer``, the
+    second its ``expert``."""
     def build(seed_key):
         flat = {}
-        for i, (name, shape, axes) in enumerate(layout(c)):
+        for i, (name, shape, axes) in enumerate(layout):
             one = lambda l, e: _draw(slice_key(seed_key, i, l, e), name,
                                      shape)
             if axes == "":
                 flat[name] = one(0, 0)
-            elif axes == "L":
-                flat[name] = jax.vmap(lambda l: one(l, 0))(jnp.arange(nl))
+            elif len(axes) == 1:
+                flat[name] = jax.vmap(lambda l: one(l, 0))(
+                    jnp.arange(counts[axes]))
             else:
                 flat[name] = jax.vmap(lambda l: jax.vmap(
-                    lambda e: one(l, e))(jnp.arange(ne)))(jnp.arange(nl))
+                    lambda e: one(l, e))(jnp.arange(counts[axes[1]])))(
+                        jnp.arange(counts[axes[0]]))
         return _nest(flat)
 
     return jax.jit(build)(base_key(seed))
 
 
-def leaf_slice(c: dict, seed: int, name: str, layer: int = 0,
+def tree_slice(layout: Layout, seed: int, name: str, layer: int = 0,
                expert: int = 0) -> jax.Array:
     """One layer's (and expert's) slice of leaf ``name``, equal to the
-    same slice of :func:`make_params`' tree."""
-    for i, (nm, shape, axes) in enumerate(layout(c)):
+    same slice of :func:`make_tree`'s tree."""
+    for i, (nm, shape, _) in enumerate(layout):
         if nm == name:
             return _slice_fn(name, shape)(slice_key(base_key(seed), i,
                                                     layer, expert))

@@ -1,6 +1,6 @@
 """Decode step: model operations of the tokens decoded in the window
-(each at its own context, with its logits; ``benchlib.roofline.
-decode_flops``) over the engine's decode seconds times the chip's bf16
+(each at its own context, with its logits; the architecture file's
+``decode_flops``) over the engine's decode seconds times the chip's bf16
 peak, in %. The whole step's share of the peak."""
 
 

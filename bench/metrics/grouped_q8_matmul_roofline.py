@@ -1,7 +1,7 @@
 """Kernels: grouped_q8_matmul's share of its roofline in the traced
 window: the least time of each call (the larger of its operations over
 the bf16 peak and its bytes over HBM bandwidth, from the call's shapes;
-``benchlib.roofline.KernelCall``), summed, over the summed device time
+``bench/kernels/grouped_matmul.py``), summed, over the summed device time
 of the kernel's events, in %."""
 
 

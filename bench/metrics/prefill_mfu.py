@@ -1,7 +1,7 @@
 """Prefill: model operations of the prompts prefilled in the window
 (real prompt tokens, the experts they are routed to, causal attention;
-``benchlib.roofline.prefill_flops``) over the engine's prefill seconds
-times the chip's bf16 peak, in %."""
+the architecture file's ``prefill_flops``) over the engine's prefill
+seconds times the chip's bf16 peak, in %."""
 
 
 def read(ctx):

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchlib import weights as W
+from archs import mixtral as W
 
 HI = jax.lax.Precision.HIGHEST
 CHUNK = 1024        # rows per token-wise call: one compiled shape

@@ -14,8 +14,8 @@ from benchlib import cell_run
 
 
 def run(tmp_path, *, hook=None, control=False):
-    cell = tiny.tiny_cell(tmp_path)
-    return cell_run.run(cell, seed=2**33 + 11, seconds=2.0,
+    cell = tiny.tiny_cell(tmp_path, "burst")
+    return cell_run.run(cell, seed=2**33 + 11, seconds=tiny.CHECK_SECONDS,
                         control=control, t_start=time.perf_counter(),
                         engine_hook=hook)
 

@@ -10,30 +10,31 @@ import pytest
 
 import tiny
 from benchlib import spec
-from benchlib import weights as W
+
+A = spec.load_module(spec.BENCH / "archs" / "mixtral.py")
 
 
 def test_a_slice_is_the_tree_s_slice():
     c = tiny.tiny_config()
-    p = W.make_params(c, 2**40 + 3)
+    p = A.make_params(c, 2**40 + 3)
     for name, ix in (("layers/moe/w_down", (1, 5)),
                      ("layers/attn/wk", (1, 0)), ("lm_head/table", (0, 0))):
         node = p
         for k in name.split("/"):
             node = node[k]
-        axes = dict((n, a) for n, _, a in W.layout(c))[name]
+        axes = dict((n, a) for n, _, a in A.layout(c))[name]
         want = node[ix[0], ix[1]] if axes == "LE" else (
             node[ix[0]] if axes == "L" else node)
-        got = W.leaf_slice(c, 2**40 + 3, name, *ix)
+        got = A.leaf_slice(c, 2**40 + 3, name, *ix)
         assert np.array_equal(np.asarray(want), np.asarray(got))
 
 
 def test_the_tree_has_the_program_s_layout():
     from benchlib.serve import program_config
     c = tiny.tiny_config()
-    cfg = program_config(c)
+    cfg = program_config(A, c)
     assert dict((n, tuple(s)) for n, s in cfg.param_shapes()) == \
-        W.full_shapes(c)
+        A.full_shapes(c)
 
 
 @pytest.mark.parametrize("bits", [4, 8])

@@ -24,6 +24,7 @@ def test_every_cell_resolves_to_its_files():
         cell = spec.resolve(w["name"])
         assert cell.chips == 1
         assert cell.reference().served_gaps
+        assert cell.arch().make_params and cell.kernels()
         assert cell.end_to_end and cell.per_layer
         for m in cell.per_layer:
             assert callable(cell.reader(m["name"]))
@@ -53,13 +54,80 @@ def test_benchmark_json_keeps_the_contract_shape():
         assert c["file"].startswith(b["paths"][0] + "/")
 
 
+#: a second architecture: Mixtral's, with every call the harness makes
+#: recorded
+TWIN_ARCH = '''
+from benchlib import spec
+
+_MIXTRAL = spec.load_module(spec.BENCH / "archs" / "mixtral.py")
+CALLS = []
+
+
+def _recorded(name):
+    def call(*args, **kw):
+        CALLS.append(name)
+        return getattr(_MIXTRAL, name)(*args, **kw)
+    return call
+
+
+for _name in ("full_shapes", "make_params", "program_config",
+              "expert_pairs", "device_parts", "prefill_flops",
+              "decode_flops"):
+    globals()[_name] = _recorded(_name)
+'''
+
+#: a kernel counter for XLA's fusions, by the bytes of their result
+FUSION_COUNTER = '''
+import re
+
+KERNELS = ("fusion",)
+
+
+def shapes(text):
+    m = re.search(r" = [a-z0-9]+\\[([0-9,]*)\\]", text)
+    return tuple(int(x) for x in m.group(1).split(",") if x) if m else None
+
+
+def flops(name, shape, c):
+    return 0.0
+
+
+def nbytes(name, shape, c):
+    n = 2.0
+    for d in shape:
+        n *= d
+    return n
+
+
+def least_s(name, shape, pk, c):
+    return nbytes(name, shape, c) / pk["hbm_bytes_per_s"]
+'''
+
+FUSION_READER = '''
+def read(ctx):
+    k = ctx["trace"]["kernels"].get("fusion")
+    return 100.0 * k["least_s"] / k["device_s"] if k else None
+'''
+
+
 def test_a_new_config_traffic_and_metric_are_files_only(tmp_path):
     """Registers throwaway files in a temporary root and resolves them by
-    the names a BENCHMARK.json gives them."""
+    the names a BENCHMARK.json gives them: a configuration of a second
+    architecture, whose file set-up and the window call, and a kernel
+    counter with its reader, which the trace reduction uses on a window
+    recorded on the chip."""
     root = tiny.make_root(tmp_path)
-    (root / "bench" / "metrics").mkdir()
+    for d in ("metrics", "archs", "kernels"):
+        (root / "bench" / d).mkdir()
     (root / "bench" / "metrics" / "answer_ms.py").write_text(
         "def read(ctx):\n    return 42.0 if ctx else None\n")
+    (root / "bench" / "archs" / "twin.py").write_text(TWIN_ARCH)
+    (root / "bench" / "kernels" / "fusion.py").write_text(FUSION_COUNTER)
+    (root / "bench" / "metrics" / "fusion_roofline.py").write_text(
+        FUSION_READER)
+    conf = root / "bench" / "configs" / "tiny.json"
+    conf.write_text(json.dumps(dict(json.loads(conf.read_text()),
+                                    arch="twin")))
     bench = json.loads((root / "BENCHMARK.json").read_text())
     bench["per_layer"] = [{"name": "answer_ms", "unit": "ms",
                            "better": "lower", "source": "program_span",
@@ -75,6 +143,33 @@ def test_a_new_config_traffic_and_metric_are_files_only(tmp_path):
     with pytest.raises(KeyError):
         spec.resolve("tiny.absent", root=root, bench=bench,
                      bench_dir=root / "bench")
+
+    from benchlib import roofline, serve
+    from benchlib import trace as TR
+    calls = cell.arch().CALLS
+    setup = serve.Setup(cell, 2**33 + 5)
+    try:
+        assert calls == ["program_config", "full_shapes", "make_params",
+                         "expert_pairs"]
+        assert setup.engine.current_plan.bits.size == 16
+        parts = setup.device_bytes()
+        win = serve.run_window(setup, 0.3)
+    finally:
+        setup.close()
+    assert set(parts) == {"train_copy_device", "train_copy_host", "banks",
+                          "kv_pool"} and parts["train_copy_host"] == 0
+    assert {"device_parts", "prefill_flops", "decode_flops"} <= set(calls)
+    assert win.prefill_flops > 0 and win.decode_flops > 0
+
+    import jax
+    pd = jax.profiler.ProfileData.from_file(
+        str(spec.BENCH / "tests" / "data" / "chat_window.xplane.pb"))
+    red = TR.reduce(pd, roofline.peaks("TPU v5 lite"), cell.config,
+                    cell.kernels())
+    assert set(red["kernels"]) == {"fusion"}
+    k = red["kernels"]["fusion"]
+    assert k["calls"] > k["unshaped"] and k["bytes"] > 0
+    assert 0 < cell.reader("fusion_roofline")({"trace": red}) < 100
 
 
 def _run(cwd, env_extra=None):

@@ -7,12 +7,16 @@ import json
 from pathlib import Path
 
 from benchlib import spec
+from benchlib import traffic as T
 
 MIXED = spec.BENCH / "configs" / "mixtral-8x7b-mixed.json"
+#: the window of the runs in bench/tests/test_bench_check.py
+CHECK_SECONDS = 0.5
 
 
-def tiny_config() -> dict:
-    c = json.loads(MIXED.read_text())
+def tiny_config(path: Path = MIXED) -> dict:
+    """The configuration file at ``path`` at test widths."""
+    c = json.loads(path.read_text())
     c.update({"hidden_size": 256, "intermediate_size": 512,
               "num_attention_heads": 4, "num_key_value_heads": 2,
               "head_dim": 64, "vocab_size": 1024, "page_size": 16,
@@ -32,7 +36,20 @@ TRAFFIC = {
                "output": {"dist": "lognormal", "median": 8, "sigma": 0.5,
                           "min": 4, "max": 16},
                "slots": 4, "max_len": 64, "check_tokens": 200},
+    # a burst of 30 requests due in CHECK_SECONDS, more than 4 slots serve
+    # in that time, so that every slot decodes; check_tokens is every
+    # answer's tokens, so the check waits for all of them and compares
+    # the same requests whatever the host's speed
+    "burst": {"loop": "open", "rate_per_s": 60.0,
+              "prompt": {"dist": "lognormal", "median": 16, "sigma": 0.8,
+                         "min": 8, "max": 48},
+              "output": {"dist": "lognormal", "median": 8, "sigma": 0.5,
+                         "min": 4, "max": 16},
+              "slots": 4, "max_len": 64},
 }
+TRAFFIC["burst"]["check_tokens"] = int(T.quantile_lengths(
+    TRAFFIC["burst"]["output"],
+    round(TRAFFIC["burst"]["rate_per_s"] * CHECK_SECONDS)).sum())
 
 
 def make_root(tmp: Path, traffic: str = "closed") -> Path:
