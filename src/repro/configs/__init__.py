@@ -5,7 +5,8 @@ import importlib
 
 from repro.configs.base import (  # noqa: F401
     AttentionConfig, LONG_CONTEXT_ARCHS, ModelConfig, MoEConfig, MoPConfig,
-    SHAPES, ShapeConfig, SSMConfig, reduce_for_smoke, shape_applicable,
+    SHAPES, ShapeConfig, SSMConfig, YarnConfig, reduce_for_smoke,
+    shape_applicable,
 )
 
 _MODULES = {
@@ -20,6 +21,7 @@ _MODULES = {
     "mixtral-8x7b": "mixtral_8x7b",
     "paligemma-3b": "paligemma_3b",
     "mixtral-mop": "mixtral_mop",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(k for k in _MODULES if k != "mixtral-mop")

@@ -22,6 +22,13 @@ class MoEConfig:
     top_k: int
     d_ff_expert: int
     capacity_factor: float = 1.25
+    # DeepSeek-V2 routing (arXiv:2405.04434): the top-k softmax weights
+    # are used as they are when ``norm_topk_prob`` is False, and scaled by
+    # ``routed_scaling_factor``; ``d_ff_shared`` > 0 adds shared experts,
+    # run as one SwiGLU MLP of that width on every token, off the ladder.
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    d_ff_shared: int = 0
     # Router jitter / z-loss are training-time details.
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
@@ -65,6 +72,22 @@ class SSMConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 states it:
+    frequencies blended between interpolated and extrapolated over the
+    dimensions between the ``beta_fast`` and ``beta_slow`` rotation
+    counts, cos/sin scaled by mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim), and the softmax scale by mscale(factor,
+    mscale_all_dim) squared."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class AttentionConfig:
     num_heads: int
     num_kv_heads: int
@@ -73,6 +96,28 @@ class AttentionConfig:
     sliding_window: Optional[int] = None   # SWA width (Mixtral: 4096)
     rope_theta: float = 1e6
     causal: bool = True
+    # Multi-head latent attention (DeepSeek-V2): every head's key and
+    # value are up-projected from one cached latent of ``kv_lora_rank``,
+    # beside one rotary key of ``qk_rope_head_dim`` shared by the heads;
+    # ``head_dim`` is then the no-position part of a query/key head and
+    # ``v_head_dim`` a value head. 0: grouped-query attention.
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # rotary scaling; latent attention turns interleaved pairs
+    yarn: Optional[YarnConfig] = None
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_values_per_position(self) -> int:
+        """Values one layer caches per position: the latent and the
+        rotary key (MLA), else a key and a value per kv head."""
+        if self.latent:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return 2 * self.num_kv_heads * self.head_dim
 
 
 @dataclass(frozen=True)
@@ -96,6 +141,12 @@ class ModelConfig:
     # precomputed embeddings of length frontend_len (per spec).
     frontend: str = "none"
     frontend_len: int = 0
+    # Leading layers with a dense MLP of width ``d_ff`` in place of the
+    # experts (DeepSeek's first_k_dense_replace); the expert plan, banks
+    # and routes cover the ``num_moe_layers`` after them.
+    first_dense_layers: int = 0
+    # Token embeddings are multiplied by this; None: sqrt(d_model).
+    embed_multiplier: Optional[float] = None
 
     act: str = "swiglu"            # swiglu|gelu|relu_sq
     norm: str = "rmsnorm"
@@ -110,6 +161,15 @@ class ModelConfig:
     @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab_size, self.vocab_pad_multiple)
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_dense_layers if self.moe else 0
+
+    @property
+    def embedding_scale(self) -> float:
+        return math.sqrt(self.d_model) if self.embed_multiplier is None \
+            else float(self.embed_multiplier)
 
     @property
     def attn_dim(self) -> int:
@@ -127,8 +187,8 @@ class ModelConfig:
             return total
         e = self.moe
         per_expert = 3 * self.d_model * e.d_ff_expert
-        experts_total = self.num_layers * e.num_experts * per_expert
-        experts_active = self.num_layers * e.top_k * per_expert
+        experts_total = self.num_moe_layers * e.num_experts * per_expert
+        experts_active = self.num_moe_layers * e.top_k * per_expert
         return total - experts_total + experts_active
 
     def expert_param_bytes(self, bits: int = 16) -> int:
@@ -147,7 +207,7 @@ class ModelConfig:
             return self.param_count() * 2
         per_expert = 3 * self.d_model * self.moe.d_ff_expert
         return (self.param_count()
-                - self.num_layers * self.moe.num_experts * per_expert) * 2
+                - self.num_moe_layers * self.moe.num_experts * per_expert) * 2
 
     def param_shapes(self):
         """(name, shape) for every parameter — single source of truth used by
@@ -169,7 +229,12 @@ class ModelConfig:
         elif self.family == "hybrid":
             kind = "mamba2"
         for nm, sh in self._block_shapes(kind=kind):
-            out.append((f"layers/{nm}", (self.num_layers,) + sh))
+            out.append((f"layers/{nm}",
+                        (self.num_layers - self.first_dense_layers,) + sh))
+        if self.first_dense_layers:
+            for nm, sh in self._attn_shapes() + self._mlp_shapes():
+                out.append((f"dense_layers/{nm}",
+                            (self.first_dense_layers,) + sh))
         if self.family == "hybrid" and self.attn_every:
             for nm, sh in self._block_shapes(kind="shared_attn"):
                 out.append((f"shared/{nm}", sh))
@@ -179,6 +244,16 @@ class ModelConfig:
         a = self.attention
         d, hd = self.d_model, a.head_dim
         pre = "cross_" if cross else ""
+        if a.latent:
+            h, r, vd = a.num_heads, a.kv_lora_rank, a.v_head_dim
+            return [
+                ("attn/wq", (d, h * (hd + a.qk_rope_head_dim))),
+                ("attn/wkv_a", (d, r + a.qk_rope_head_dim)),
+                ("attn/kv_norm/scale", (r,)),
+                ("attn/wkv_b", (r, h * (hd + vd))),
+                ("attn/wo", (h * vd, d)),
+                ("attn_norm/scale", (d,)),
+            ]
         sh = [
             (f"{pre}attn/wq", (d, a.num_heads * hd)),
             (f"{pre}attn/wk", (d, a.num_kv_heads * hd)),
@@ -194,14 +269,23 @@ class ModelConfig:
         d = self.d_model
         if self.moe is not None:
             e = self.moe
-            return [
+            sh = [
                 ("moe/router", (d, e.num_experts)),
                 ("moe/w_gate", (e.num_experts, d, e.d_ff_expert)),
                 ("moe/w_up", (e.num_experts, d, e.d_ff_expert)),
                 ("moe/w_down", (e.num_experts, e.d_ff_expert, d)),
                 ("ffn_norm/scale", (d,)),
             ]
-        f = self.d_ff
+            if e.d_ff_shared:
+                ds = e.d_ff_shared
+                sh += [("shared_mlp/w_gate", (d, ds)),
+                       ("shared_mlp/w_up", (d, ds)),
+                       ("shared_mlp/w_down", (ds, d))]
+            return sh
+        return self._mlp_shapes()
+
+    def _mlp_shapes(self):
+        d, f = self.d_model, self.d_ff
         sh = [("mlp/w_up", (d, f)), ("mlp/w_down", (f, d)),
               ("ffn_norm/scale", (d,))]
         if self.act == "swiglu":
@@ -310,13 +394,18 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
             a, num_heads=4, num_kv_heads=max(1, min(a.num_kv_heads, 2)),
             head_dim=16,
             sliding_window=64 if a.sliding_window else None)
+        if a.latent:
+            kw["attention"] = dataclasses.replace(
+                kw["attention"], kv_lora_rank=32, qk_rope_head_dim=8,
+                v_head_dim=16)
     if cfg.moe:
         # capacity_factor=8 -> no token dropping at smoke scale, so the
         # decode==prefill invariant holds exactly
         kw["moe"] = dataclasses.replace(
             cfg.moe, num_experts=min(cfg.moe.num_experts, 8),
             top_k=min(cfg.moe.top_k, 2), d_ff_expert=64,
-            capacity_factor=8.0)
+            capacity_factor=8.0,
+            d_ff_shared=128 if cfg.moe.d_ff_shared else 0)
     if cfg.ssm:
         kw["ssm"] = dataclasses.replace(
             cfg.ssm, state_dim=16, head_dim=16, chunk_size=16)
@@ -326,6 +415,8 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         kw["attn_every"] = 2
     if cfg.frontend != "none":
         kw["frontend_len"] = 8
+    if cfg.first_dense_layers:
+        kw["first_dense_layers"] = 1
     if cfg.mop.enabled:
         kw["mop"] = dataclasses.replace(cfg.mop, group_size=16)
     return cfg.replace(**kw)

@@ -294,21 +294,21 @@ def draft_token_time(cfg: ModelConfig, plan: PrecisionPlan,
         / hw.q_speedup_decode(low) * (16 / low) if low < 16 \
         else float(cfg.expert_param_bytes(16))
     weight_bytes = cfg.non_expert_bytes() \
-        + cfg.num_layers * e.top_k * per_active
+        + cfg.num_moe_layers * e.top_k * per_active
     t = weight_bytes / (hw.hbm_bw * hw.mbu)
     if hw.kernel_launch_s > 0.0:
         # all experts on one rung: one grouped launch per layer.
-        launches = cfg.num_layers if hw.grouped_ffn \
+        launches = cfg.num_moe_layers if hw.grouped_ffn \
             else int((plan.location == DEVICE).sum())
         t += launches * hw.kernel_launch_s
     return t
 
 
 def kv_token_bytes(cfg: ModelConfig) -> int:
-    """KV bytes one cached token costs across the stack (k + v)."""
-    a = cfg.attention
+    """KV bytes one cached token costs across the stack (k + v, or the
+    latent and rotary key under latent attention)."""
     itemsize = 2 if cfg.dtype in ("bfloat16", "float16") else 4
-    return cfg.num_layers * 2 * a.num_kv_heads * a.head_dim * itemsize
+    return cfg.num_layers * cfg.attention.kv_values_per_position * itemsize
 
 
 def kv_bytes_bucketed(cfg: ModelConfig, slots: int, window: int) -> int:
@@ -346,7 +346,7 @@ def estimate_qos(cfg: ModelConfig, plan: PrecisionPlan,
             / hw.q_speedup_decode(b) * (16 / b)
         frac_q_sum += frac
     per_active += (1 - frac_q_sum) * s16
-    active_expert_bytes = cfg.num_layers * e.top_k * per_active
+    active_expert_bytes = cfg.num_moe_layers * e.top_k * per_active
     weight_bytes = cfg.non_expert_bytes() + active_expert_bytes
     t_compute = weight_bytes / (hw.hbm_bw * hw.mbu)
     if hw.kernel_launch_s > 0.0:

@@ -77,7 +77,11 @@ class capture_moe_inputs:
 
 def route(router_w: jax.Array, x: jax.Array, moe: MoEConfig, *,
           train: bool) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
-    """x: (T, d) -> (weights (T,k) f32, ids (T,k) i32, aux losses)."""
+    """x: (T, d) -> (weights (T,k) f32, ids (T,k) i32, aux losses).
+
+    The top-k softmax weights are renormalised to sum to one unless
+    ``moe.norm_topk_prob`` is False, then scaled by
+    ``moe.routed_scaling_factor``."""
     logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, ids = jax.lax.top_k(probs, moe.top_k)
@@ -88,7 +92,10 @@ def route(router_w: jax.Array, x: jax.Array, moe: MoEConfig, *,
     if moe_trace is not None and not isinstance(probs, jax.core.Tracer):
         moe_trace.append((np.asarray(x, np.float32),
                           np.asarray(probs, np.float32)))
-    weights = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    weights = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9) \
+        if moe.norm_topk_prob else top_p
+    if moe.routed_scaling_factor != 1.0:
+        weights = weights * moe.routed_scaling_factor
     aux: Dict[str, jax.Array] = {}
     if train:
         e = moe.num_experts

@@ -232,7 +232,7 @@ class ParetoFrontier:
         #: uniform profile) keeps the legacy flat pricing bit-for-bit.
         self.profile = profile
         self.ladder = validate_ladder(cfg.mop.precision_ladder)
-        layers = cfg.num_layers
+        layers = cfg.num_moe_layers
         e = cfg.moe.num_experts
         total = layers * e
         step = residency_step or layers

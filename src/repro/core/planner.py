@@ -123,7 +123,7 @@ class AdaptivePlanner:
 
     @property
     def num_experts_total(self) -> int:
-        return self.cfg.num_layers * self.cfg.moe.num_experts
+        return self.cfg.num_moe_layers * self.cfg.moe.num_experts
 
     # -- planning ---------------------------------------------------------
     def plan(self, mem_budget_bytes: float, preference: Preference,
@@ -145,7 +145,7 @@ class AdaptivePlanner:
                 f"infeasible budget {mem_budget_bytes/2**20:.1f} MiB < "
                 f"non-expert floor {self.size_ne/2**20:.1f} MiB")
         total = self.num_experts_total
-        layers = self.cfg.num_layers
+        layers = self.cfg.num_moe_layers
         low = quantized_rungs(self.ladder)[0]
         if preference == "throughput":
             if counts is not None:
@@ -188,7 +188,7 @@ class AdaptivePlanner:
             peer = 0
 
         plan = balanced_ladder_plan(
-            self.cfg.num_layers, self.cfg.moe.num_experts, counts,
+            self.cfg.num_moe_layers, self.cfg.moe.num_experts, counts,
             ladder=self.ladder, group_size=self.cfg.mop.group_size,
             seed=self.seed, resident_experts=resident,
             peer_experts=peer)
@@ -208,7 +208,7 @@ class AdaptivePlanner:
         per-layer counts additionally round DOWN to multiples of
         ``self.ep`` so every rung bank splits evenly over the mesh
         (mixed_moe's dispatch invariant — DESIGN.md §16)."""
-        layers = self.cfg.num_layers
+        layers = self.cfg.num_moe_layers
         e = self.cfg.moe.num_experts
         out: Dict[int, int] = {}
         room = e

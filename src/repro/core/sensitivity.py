@@ -71,7 +71,7 @@ class SensitivityProfile:
         priced at ``RUNG_QUALITY_COST[b]``, uniform traffic."""
         assert cfg.moe is not None
         ladder = tuple(ladder if ladder is not None else cfg.mop.precision_ladder)
-        shape = (cfg.num_layers, cfg.moe.num_experts)
+        shape = (cfg.num_moe_layers, cfg.moe.num_experts)
         sens = {int(b): np.full(shape, RUNG_QUALITY_COST[int(b)], np.float64)
                 for b in quantized_rungs(ladder)}
         freq = np.full(shape, 1.0 / (shape[0] * shape[1]), np.float64)
@@ -205,7 +205,7 @@ def calibrate_sensitivity(cfg: ModelConfig, params, *, seed: int = 0,
     assert cfg.moe is not None, "sensitivity calibration needs a MoE arch"
     ladder = tuple(ladder if ladder is not None else cfg.mop.precision_ladder)
     gs = int(group_size if group_size is not None else cfg.mop.group_size)
-    num_layers, num_experts = cfg.num_layers, cfg.moe.num_experts
+    num_layers, num_experts = cfg.num_moe_layers, cfg.moe.num_experts
 
     rng = np.random.default_rng(seed)
     tokens = rng.integers(1, cfg.vocab_size,

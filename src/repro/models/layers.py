@@ -11,10 +11,12 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import AttentionConfig
 from repro.dist.sharding import constrain
@@ -40,6 +42,57 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
     ).astype(x.dtype)
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention factor for a context stretched ``scale`` times."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def rotary_inv_freq(acfg: AttentionConfig, dim: int) -> np.ndarray:
+    """(dim // 2,) float32 inverse frequencies of a rotary of width
+    ``dim``: theta's ladder, or with ``acfg.yarn`` the blend of its
+    interpolated (``/ factor``) and extrapolated frequencies over the
+    dimensions between the ``beta_fast`` and ``beta_slow`` rotation
+    counts at ``original_max_position_embeddings``."""
+    base = float(acfg.rope_theta)
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    y = acfg.yarn
+    if y is None:
+        return extra.astype(np.float32)
+
+    def corr(rotations):
+        return dim * math.log(y.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(corr(y.beta_fast)), 0)
+    high = min(math.ceil(corr(y.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / (high - low if high > low else 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp                      # share kept extrapolated
+    return (extra / y.factor * ramp + extra * keep).astype(np.float32)
+
+
+def _rotary(acfg: AttentionConfig, x: jax.Array,
+            positions: jax.Array) -> jax.Array:
+    """Rotary of x (B, S, H, d) at ``positions`` (B, S) on interleaved
+    pairs (x[2i], x[2i+1]), as DeepSeek-V2's complex form turns them,
+    at ``rotary_inv_freq``; YaRN scales cos and sin by mscale(factor,
+    mscale) / mscale(factor, mscale_all_dim)."""
+    d = x.shape[-1]
+    ang = positions[..., None].astype(jnp.float32) \
+        * jnp.asarray(rotary_inv_freq(acfg, d))
+    y = acfg.yarn
+    m = 1.0 if y is None else \
+        yarn_mscale(y.factor, y.mscale) / yarn_mscale(y.factor,
+                                                     y.mscale_all_dim)
+    cos = (jnp.cos(ang) * m)[..., None, :]
+    sin = (jnp.sin(ang) * m)[..., None, :]
+    xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    x1, x2 = xr[..., 0], xr[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -88,30 +141,36 @@ def _spec_update_cache(cache, k_new, v_new, positions):
     return {"k": k, "v": v, "pos": pos}
 
 
-def _prefill_cache(cache, k_new, v_new, positions):
-    """Prefill-from-empty cache write WITHOUT a scatter.
+def _ring_prefill(cache, new: Dict[str, jax.Array], positions):
+    """Prefill-from-empty ring write of the ``new`` leaves (B, S, ...)
+    WITHOUT a scatter.
 
     Positions are contiguous 0..S-1, so the ring-buffer content is a
-    (rolled) slice of k/v — a reshape GSPMD partitions for free, vs. the
-    general scatter which all-gathers the whole cache per layer."""
-    b, s, hkv, hd = k_new.shape
-    window = cache["k"].shape[1]
+    (rolled) slice of the leaves — a reshape GSPMD partitions for free,
+    vs. the general scatter which all-gathers the whole cache per layer."""
+    s = positions.shape[1]
+    window = cache["pos"].shape[1]
     if s >= window:
         shift = (s - window) % window      # slot of the first kept entry
         cut = lambda a: jnp.roll(a[:, -window:], shift, axis=1)
-        k, v, pos = cut(k_new), cut(v_new), cut(positions)
+        out = {k: cut(v) for k, v in new.items()}
+        pos = cut(positions)
     else:
-        pad = [(0, 0), (0, window - s)] + [(0, 0)] * (k_new.ndim - 2)
-        k = jnp.pad(k_new, pad)
-        v = jnp.pad(v_new, pad)
+        out = {k: jnp.pad(v, [(0, 0), (0, window - s)]
+                          + [(0, 0)] * (v.ndim - 2))
+               for k, v in new.items()}
         pos = jnp.pad(positions, [(0, 0), (0, window - s)],
                       constant_values=-1)
+    out = {k: v.astype(cache[k].dtype) for k, v in out.items()}
+    out["pos"] = pos.astype(jnp.int32)
+    return out
+
+
+def _prefill_cache(cache, k_new, v_new, positions):
     # NOTE: no sharding constraint here — constraining would CSE with the
     # in-context attention's k/v and drag a seq-gather into every layer;
     # the stacked cache output is resharded once at the jit boundary.
-    return {"k": k.astype(cache["k"].dtype),
-            "v": v.astype(cache["v"].dtype),
-            "pos": pos.astype(jnp.int32)}
+    return _ring_prefill(cache, {"k": k_new, "v": v_new}, positions)
 
 
 _Q_CHUNK = 512      # query-block size for long-sequence attention
@@ -197,6 +256,9 @@ def attention(p: Dict[str, Any], x: jax.Array, acfg: AttentionConfig, *,
     tokens extend a LIVE cache (never the prefill-from-empty rewrite) and
     rows tagged position=-1 are dropped instead of aliased by the modulo.
     """
+    if acfg.latent:
+        return latent_attention(p, x, acfg, positions=positions,
+                                cache=cache, spec=spec)
     b, s, d = x.shape
     h, hkv, hd = acfg.num_heads, acfg.num_kv_heads, acfg.head_dim
     src = kv_x if kv_x is not None else x
@@ -269,6 +331,131 @@ def attention(p: Dict[str, Any], x: jax.Array, acfg: AttentionConfig, *,
         out = _sdpa(q, k, v, mask, "attn_scores_full", grouped=g_full)
 
     return out.reshape(b, s, h * hd) @ p["wo"], new_cache
+
+
+def _attend(q, k, v, mask, scale: float) -> jax.Array:
+    """q, k: (B, S, H, dq); v: (B, Sk, H, dv); mask (B, 1, Sq, Sk).
+    Query blocks of _Q_CHUNK past 2 * _Q_CHUNK, exact softmax."""
+    def block(qb, mb):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", qb, k,
+                            preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(mb, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhe->bqhe", probs, v)
+
+    b, sq, h, _ = q.shape
+    if sq <= 2 * _Q_CHUNK or sq % _Q_CHUNK:
+        return block(q, mask)
+    nb = sq // _Q_CHUNK
+    qs = q.reshape(b, nb, _Q_CHUNK, h, -1).transpose(1, 0, 2, 3, 4)
+    ms = mask.reshape(b, 1, nb, _Q_CHUNK, -1).transpose(2, 0, 1, 3, 4)
+    _, out = jax.lax.scan(lambda _, qm: (None, block(*qm)), None, (qs, ms))
+    return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, -1)
+
+
+def latent_scale(acfg: AttentionConfig) -> float:
+    """Softmax scale of latent attention: the query/key head width's
+    inverse square root, times YaRN's mscale(factor, mscale_all_dim)
+    squared (DeepSeek-V2's published modeling code)."""
+    scale = (acfg.head_dim + acfg.qk_rope_head_dim) ** -0.5
+    y = acfg.yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def latent_attention(p: Dict[str, Any], x: jax.Array,
+                     acfg: AttentionConfig, *, positions: jax.Array,
+                     cache: Optional[Dict[str, jax.Array]] = None,
+                     spec: bool = False,
+                     ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434).
+
+    ``wkv_a`` gives the latent ``c`` (``kv_lora_rank``, through its
+    RMSNorm) and one rotary key shared by the heads; the cache holds
+    their concatenation, ``kv`` (B, W, kv_lora_rank + qk_rope_head_dim),
+    one vector a position, beside the position tags ``pos``.
+
+    Without a cache, and for a prefill (S > 1) into a fresh one,
+    attention runs expanded: ``wkv_b`` up-projects ``c`` to every head's
+    no-position key and value; the prefill returns the written ring.
+
+    A decode step (S == 1) runs absorbed and reads ``cache`` in place:
+    the key half of ``wkv_b`` is folded into the query, scores are taken
+    against the cached latents of the positions before the token (inside
+    the ring) and against the token's own latent, and the value half is
+    applied to the attended latent. It returns only the entry it wrote,
+    ``{"kv": (B, 1, ...), "pos": (B, 1)}``, for the caller to store."""
+    if spec:
+        raise NotImplementedError("speculative steps over a latent cache")
+    b, s, _ = x.shape
+    h, hd, rd = acfg.num_heads, acfg.head_dim, acfg.qk_rope_head_dim
+    r, vd = acfg.kv_lora_rank, acfg.v_head_dim
+    q = (x @ p["wq"]).reshape(b, s, h, hd + rd)
+    q_nope, q_rope = q[..., :hd], _rotary(acfg, q[..., hd:], positions)
+    kv = x @ p["wkv_a"]
+    c = rms_norm(kv[..., :r], p["kv_norm"]["scale"])
+    k_rope = _rotary(acfg, kv[:, :, None, r:], positions)[:, :, 0]
+    latent = jnp.concatenate([c, k_rope], axis=-1)       # (B, S, r + rd)
+    w_kv = p["wkv_b"].reshape(r, h, hd + vd)
+    scale = latent_scale(acfg)
+    if cache is not None and s == 1:
+        ring, kpos = cache["kv"], cache["pos"]             # (B, W, r + rd)
+        qpos = positions[:, :, None]                       # (B, 1, 1)
+        # the ring's entries before the token and inside the window (its
+        # own slot's old entry is the one the write evicts)
+        seen = (kpos[:, None, :] >= 0) & (kpos[:, None, :] < qpos) \
+            & (qpos - kpos[:, None, :] < kpos.shape[1])
+        if acfg.sliding_window:
+            seen &= qpos - kpos[:, None, :] < acfg.sliding_window
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_kv[..., :hd],
+                           preferred_element_type=jnp.float32)
+        qc = jnp.concatenate([q_lat.astype(x.dtype), q_rope], axis=-1)
+        logits = jnp.concatenate([
+            jnp.einsum("bshc,bwc->bhsw", qc, ring,
+                       preferred_element_type=jnp.float32),
+            jnp.einsum("bshc,bsc->bhs", qc, latent,
+                       preferred_element_type=jnp.float32)[..., None],
+        ], axis=-1) * scale
+        mask = jnp.concatenate([seen, (qpos >= 0)], axis=-1)[:, None]
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+        w = ring.shape[1]
+        o_lat = jnp.einsum("bhsw,bwr->bshr", probs[..., :w], ring[..., :r]) \
+            + jnp.einsum("bhs,bsr->bshr", probs[..., w], c)
+        out = jnp.einsum("bshr,rhv->bshv", o_lat, w_kv[..., hd:])
+        new_cache = {"kv": latent.astype(ring.dtype), "pos": positions}
+    else:
+        kvb = jnp.einsum("bsr,rhe->bshe", c, w_kv)
+        k = jnp.concatenate(
+            [kvb[..., :hd],
+             jnp.broadcast_to(k_rope[:, :, None], (b, s, h, rd))], axis=-1)
+        qf = jnp.concatenate([q_nope, q_rope], axis=-1)
+        qpos = positions
+        mask = qpos[:, None, :, None] >= qpos[:, None, None, :]
+        new_cache = None
+        if cache is not None:
+            # right-padded slot prefills tag pads with pos=-1
+            mask &= qpos[:, None, None, :] >= 0
+            new_cache = _ring_prefill(cache, {"kv": latent}, positions)
+        if acfg.sliding_window:
+            mask &= (qpos[:, None, :, None] - qpos[:, None, None, :]
+                     < acfg.sliding_window)
+        out = _attend(qf, k, kvb[..., hd:], mask, scale)
+    return out.reshape(b, s, h * vd) @ p["wo"], new_cache
+
+
+def write_entries(ring: Dict[str, jax.Array],
+                  entries: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """Store latent decode entries (L, B, S, ...), tagged by their
+    positions, into a stacked ring cache (L, B, W, ...); rows tagged
+    position<0 are dropped."""
+    window = ring["pos"].shape[2]
+    pos = entries["pos"][0]                                    # (B, S)
+    slots = jnp.where(pos >= 0, pos % window, window)
+    b_idx = jnp.arange(pos.shape[0])[:, None]
+    return {k: ring[k].at[:, b_idx, slots].set(entries[k], mode="drop")
+            for k in ring}
 
 
 def mlp(p: Dict[str, Any], x: jax.Array, act: str) -> jax.Array:

@@ -80,6 +80,25 @@ def abstract_params(cfg: ModelConfig) -> Dict[str, Any]:
 # Cache construction
 # ---------------------------------------------------------------------------
 
+def kv_leaves(cfg: ModelConfig) -> Dict[str, tuple]:
+    """The cache's per-position leaves and their trailing shapes: one
+    latent vector (latent attention) or a key and a value per kv head."""
+    a = cfg.attention
+    if a.latent:
+        return {"kv": (a.kv_values_per_position,)}
+    return {"k": (a.num_kv_heads, a.head_dim),
+            "v": (a.num_kv_heads, a.head_dim)}
+
+
+def _fresh_ring(cfg: ModelConfig, n: int, batch: int, window: int,
+                dtype) -> Dict[str, jax.Array]:
+    """An empty (n, batch, window, ...) ring cache: zeros, tags -1."""
+    ring = {k: jnp.zeros((n, batch, window) + sh, dtype)
+            for k, sh in kv_leaves(cfg).items()}
+    ring["pos"] = jnp.full((n, batch, window), -1, jnp.int32)
+    return ring
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                abstract: bool = False) -> Any:
     """Decode caches; ``abstract=True`` returns ShapeDtypeStructs."""
@@ -89,11 +108,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
               else jnp.full(s, -1, d))
 
     def kv(n, window):
-        return {"k": mk((n, batch, window, cfg.attention.num_kv_heads,
-                         cfg.attention.head_dim)),
-                "v": mk((n, batch, window, cfg.attention.num_kv_heads,
-                         cfg.attention.head_dim)),
-                "pos": mk((n, batch, window), jnp.int32)}
+        ring = {k: mk((n, batch, window) + sh)
+                for k, sh in kv_leaves(cfg).items()}
+        ring["pos"] = mk((n, batch, window), jnp.int32)
+        return ring
 
     fam = cfg.family
     if fam in ("dense", "moe", "vlm"):
@@ -177,14 +195,13 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
         raise ValueError(f"pool of {num_pages} pages cannot hold even one "
                          f"full window ({chunks} pages)")
     dt = jnp.dtype(cfg.dtype)
-    hkv, hd = cfg.attention.num_kv_heads, cfg.attention.head_dim
     n = cfg.num_layers
     mk = (lambda s, d=dt: jax.ShapeDtypeStruct(s, d)) if abstract \
         else (lambda s, d=dt: jnp.zeros(s, d) if d != jnp.int32
               else jnp.full(s, -1, d))
-    pool = {"k": mk((n, num_pages, page_size, hkv, hd)),
-            "v": mk((n, num_pages, page_size, hkv, hd)),
-            "pos": mk((n, num_pages, page_size), jnp.int32)}
+    pool = {k: mk((n, num_pages, page_size) + sh)
+            for k, sh in kv_leaves(cfg).items()}
+    pool["pos"] = mk((n, num_pages, page_size), jnp.int32)
     return pool, PagedKVMeta(window=window, page_size=page_size,
                              chunks_per_slot=chunks, num_pages=num_pages)
 
@@ -207,7 +224,7 @@ def _gather_paged(pool, pt, window: int):
         l, b, _, ps = x.shape[:4]
         return x.reshape((l, b, nc * ps) + x.shape[4:])[:, :, :window]
 
-    return {"k": g(pool["k"]), "v": g(pool["v"]), "pos": g(pool["pos"])}
+    return {key: g(a) for key, a in pool.items()}
 
 
 def _scatter_paged(pool, pt, ring, window: int):
@@ -219,8 +236,7 @@ def _scatter_paged(pool, pt, ring, window: int):
     spt = _scatter_table(pt, pool["pos"].shape[1])
     pad = nc * ps - window
     out = {}
-    for key in ("k", "v", "pos"):
-        r = ring[key]
+    for key, r in ring.items():
         if pad:
             cfgp = [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (r.ndim - 3)
             r = jnp.pad(r, cfgp,
@@ -241,7 +257,7 @@ def _gather_paged_layer(pool, pt, window: int, layer):
         b, _, ps = x.shape[:3]
         return x.reshape((b, nc * ps) + x.shape[3:])[:, :window]
 
-    return {"k": g(pool["k"]), "v": g(pool["v"]), "pos": g(pool["pos"])}
+    return {key: g(a) for key, a in pool.items()}
 
 
 def _scatter_paged_layer(pool, pt, ring, window: int, layer):
@@ -250,8 +266,7 @@ def _scatter_paged_layer(pool, pt, ring, window: int, layer):
     spt = _scatter_table(pt, pool["pos"].shape[1])
     pad = nc * ps - window
     out = {}
-    for key in ("k", "v", "pos"):
-        r = ring[key]
+    for key, r in ring.items():
         if pad:
             cfgp = [(0, 0), (0, pad)] + [(0, 0)] * (r.ndim - 2)
             r = jnp.pad(r, cfgp,
@@ -271,8 +286,7 @@ def _scatter_prefill_paged(pool, page_row, ring, window: int):
     spt = _scatter_table(page_row, pool["pos"].shape[1])
     pad = nc * ps - window
     out = {}
-    for key in ("k", "v", "pos"):
-        r = ring[key]                          # (L, W, ...)
+    for key, r in ring.items():                # (L, W, ...)
         if pad:
             cfgp = [(0, 0), (0, pad)] + [(0, 0)] * (r.ndim - 2)
             r = jnp.pad(r, cfgp,
@@ -281,6 +295,35 @@ def _scatter_prefill_paged(pool, page_row, ring, window: int):
         rr = r.reshape((l, nc, ps) + r.shape[2:])
         out[key] = pool[key].at[:, spt].set(rr, mode="drop")
     return out
+
+
+def _scatter_entries_paged(pool, pt, entries, window: int):
+    """Write new entries (L, B, S, ...), tagged by their positions, into
+    the pages that hold those positions, one (row, token) at a time in
+    place (a scatter over the page axis relays the whole pool out);
+    rows tagged position<0 and unmapped chunks leave the pool as it
+    was."""
+    pos = entries["pos"][0]                                    # (B, S)
+    ps = pool["pos"].shape[2]
+    ring_slot = pos % window
+    page = jnp.take_along_axis(pt, ring_slot // ps, axis=1)
+    page = jnp.where(pos >= 0, page, 0)                   # 0: the null page
+    off = ring_slot % ps
+    n_tok = pos.shape[1]
+
+    def write(i, pool):
+        b, j = i // n_tok, i % n_tok
+        out = {}
+        for key, a in pool.items():
+            at = (0, page[b, j], off[b, j]) + (0,) * (a.ndim - 3)
+            old = jax.lax.dynamic_slice(a, at, (a.shape[0], 1, 1)
+                                        + a.shape[3:])
+            new = entries[key][:, b, j].reshape(old.shape)
+            out[key] = jax.lax.dynamic_update_slice(
+                a, jnp.where(page[b, j] > 0, new, old), at)
+        return out
+
+    return jax.lax.fori_loop(0, pos.size, write, pool)
 
 
 def paged_reset_pages(pool, pages: jax.Array):
@@ -365,11 +408,16 @@ class Model:
     # (pool, page_table (B,nc), keep (B,)) -> pool, same contract
 
 
+def _embed_tokens(params, cfg: ModelConfig, tokens):
+    """Token ids -> embeddings times ``cfg.embedding_scale``."""
+    table = params["embed"]["table"]
+    return L.embed(table, tokens) \
+        * jnp.asarray(cfg.embedding_scale, table.dtype)
+
+
 def _embed_inputs(params, cfg: ModelConfig, batch):
     """tokens (+ frontend embeddings) -> (x (B,S,d), positions (B,S))."""
-    tok = batch["tokens"]
-    x = L.embed(params["embed"]["table"], tok) \
-        * jnp.asarray(math.sqrt(cfg.d_model), params["embed"]["table"].dtype)
+    x = _embed_tokens(params, cfg, batch["tokens"])
     if cfg.frontend == "vision":
         x = jnp.concatenate([batch["frontend"].astype(x.dtype), x], axis=1)
     positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
@@ -444,9 +492,7 @@ def build_model(cfg: ModelConfig, mesh=None, *,
         Idle slots pass position=-1: their ring-buffer write lands with an
         invalid (-1) tag, so a retired slot never pollutes its cache row."""
         with act_ctx():
-            x = L.embed(params["embed"]["table"], tokens) \
-                * jnp.asarray(math.sqrt(cfg.d_model),
-                              params["embed"]["table"].dtype)
+            x = _embed_tokens(params, cfg, tokens)
             pos2 = positions[:, None]
             kw = dict(par=par, train=False, use_kernel=use_kernel)
             if cfg.family == "encdec":
@@ -481,24 +527,18 @@ def build_model(cfg: ModelConfig, mesh=None, *,
         ``slot`` and ``last_idx`` (index of the last real token) are traced
         scalars — one compile per padded length, none per slot. Returns
         (next-token logits (1, V), cache with slot row replaced)."""
-        window = cache["k"].shape[2]
+        window = cache["pos"].shape[2]
         with act_ctx():
-            x = L.embed(params["embed"]["table"], tokens) \
-                * jnp.asarray(math.sqrt(cfg.d_model),
-                              params["embed"]["table"].dtype)
-            n, _, _, hkv, hd = cache["k"].shape
-            sub = {"k": jnp.zeros((n, 1, window, hkv, hd),
-                                  cache["k"].dtype),
-                   "v": jnp.zeros((n, 1, window, hkv, hd),
-                                  cache["v"].dtype),
-                   "pos": jnp.full((n, 1, window), -1, jnp.int32)}
+            x = _embed_tokens(params, cfg, tokens)
+            sub = _fresh_ring(cfg, cache["pos"].shape[0], 1, window,
+                              jnp.dtype(cfg.dtype))
             y, new_sub, _ = fwd(params, cfg, x, positions, caches=sub,
                                 par=par, train=False, use_kernel=use_kernel)
             y_last = jnp.take(y, last_idx, axis=1, mode="clip")[:, None]
             y_last = L.rms_norm(y_last, params["final_norm"]["scale"])
             logits = L.unembed(params["lm_head"]["table"], y_last)
             merged = {key: cache[key].at[:, slot].set(new_sub[key][:, 0])
-                      for key in ("k", "v", "pos")}
+                      for key in cache}
             return logits[:, 0], merged
 
     def reset_slot(cache, slot):
@@ -509,9 +549,7 @@ def build_model(cfg: ModelConfig, mesh=None, *,
     # -- per-layer decode (async overlap pipeline, DESIGN.md §12) ----------
     def decode_embed(params, tokens):
         """tokens (B,1) -> embedded x (B,1,d); the pipeline's front."""
-        return L.embed(params["embed"]["table"], tokens) \
-            * jnp.asarray(math.sqrt(cfg.d_model),
-                          params["embed"]["table"].dtype)
+        return _embed_tokens(params, cfg, tokens)
 
     def decode_layer_routed(params, cache, x, positions, layer):
         """One decoder block of the stacked params at index ``layer`` (a
@@ -523,7 +561,7 @@ def build_model(cfg: ModelConfig, mesh=None, *,
         with act_ctx():
             p = jax.tree_util.tree_map(lambda v: v[layer],
                                        params["layers"])
-            c = {k: cache[k][layer] for k in ("k", "v", "pos")}
+            c = {k: a[layer] for k, a in cache.items()}
             pos2 = positions[:, None]
             token_valid = pos2 >= 0
             h, new_kv = L.attention(
@@ -535,7 +573,7 @@ def build_model(cfg: ModelConfig, mesh=None, *,
                                     {}, token_valid=token_valid)
             x = L.constrain(x + h, "residual")
             merged = {k: cache[k].at[layer].set(new_kv[k])
-                      for k in ("k", "v", "pos")}
+                      for k in cache}
             return x, merged, ids
 
     def decode_logits(params, x):
@@ -551,21 +589,15 @@ def build_model(cfg: ModelConfig, mesh=None, *,
         are independent of the cache layout), then the written ring is
         scattered chunk-wise into the slot's mapped pages."""
         with act_ctx():
-            x = L.embed(params["embed"]["table"], tokens) \
-                * jnp.asarray(math.sqrt(cfg.d_model),
-                              params["embed"]["table"].dtype)
-            n, _, _, hkv, hd = pool["k"].shape
-            sub = {"k": jnp.zeros((n, 1, window, hkv, hd),
-                                  pool["k"].dtype),
-                   "v": jnp.zeros((n, 1, window, hkv, hd),
-                                  pool["v"].dtype),
-                   "pos": jnp.full((n, 1, window), -1, jnp.int32)}
+            x = _embed_tokens(params, cfg, tokens)
+            sub = _fresh_ring(cfg, pool["pos"].shape[0], 1, window,
+                              jnp.dtype(cfg.dtype))
             y, new_sub, _ = fwd(params, cfg, x, positions, caches=sub,
                                 par=par, train=False, use_kernel=use_kernel)
             y_last = jnp.take(y, last_idx, axis=1, mode="clip")[:, None]
             y_last = L.rms_norm(y_last, params["final_norm"]["scale"])
             logits = L.unembed(params["lm_head"]["table"], y_last)
-            ring = {key: new_sub[key][:, 0] for key in ("k", "v", "pos")}
+            ring = {key: a[:, 0] for key, a in new_sub.items()}
             return logits[:, 0], _scatter_prefill_paged(pool, page_row,
                                                         ring, window)
 
@@ -573,7 +605,17 @@ def build_model(cfg: ModelConfig, mesh=None, *,
                                  positions, *, window):
         """Paged ``decode_step_routed``: gather the page view into the
         standard ring cache, run the identical decode step, scatter the
-        updated ring back. Bit-identical logits (tested)."""
+        updated ring back. Bit-identical logits (tested).
+
+        Under latent attention each layer reads its own page view in
+        place and the step writes back only the new entries."""
+        if cfg.attention.latent:
+            logits, entries, route_ids = _decode_step(
+                params, functools.partial(_gather_paged_layer, pool,
+                                          page_table, window),
+                tokens, positions, True)
+            return logits, _scatter_entries_paged(
+                pool, page_table, entries, window), route_ids
         ring = _gather_paged(pool, page_table, window)
         logits, new_ring, route_ids = _decode_step(
             params, ring, tokens, positions, True)
@@ -611,9 +653,7 @@ def build_model(cfg: ModelConfig, mesh=None, *,
         expert ids (L, B*S, top_k) with padded rows remapped to the
         sentinel ``num_experts``."""
         with act_ctx():
-            x = L.embed(params["embed"]["table"], tokens) \
-                * jnp.asarray(math.sqrt(cfg.d_model),
-                              params["embed"]["table"].dtype)
+            x = _embed_tokens(params, cfg, tokens)
             y, new_cache, aux = fwd(params, cfg, x, positions,
                                     caches=cache, par=par, train=False,
                                     use_kernel=use_kernel,
@@ -650,7 +690,12 @@ def build_model(cfg: ModelConfig, mesh=None, *,
         return dict(pool,
                     pos=pool["pos"].at[:, spt].set(pos, mode="drop"))
 
-    layered_api = slot_api and cfg.moe is not None
+    # the per-layer hooks index one stack of ring caches: not with
+    # leading dense layers, nor over a latent cache
+    layered_api = slot_api and cfg.moe is not None \
+        and not cfg.first_dense_layers and not cfg.attention.latent
+    spec_api = slot_api and cfg.moe is not None \
+        and not cfg.attention.latent
 
     return Model(
         cfg=cfg,
@@ -675,10 +720,9 @@ def build_model(cfg: ModelConfig, mesh=None, *,
         paged_decode_layer_routed=paged_decode_layer_routed
         if layered_api else None,
         paged_reset_pages=paged_reset_pages if slot_api else None,
-        spec_step_routed=spec_step_routed
-        if slot_api and cfg.moe is not None else None,
-        paged_spec_step_routed=paged_spec_step_routed
-        if slot_api and cfg.moe is not None else None,
+        spec_step_routed=spec_step_routed if spec_api else None,
+        paged_spec_step_routed=paged_spec_step_routed if spec_api
+        else None,
         rollback_slots=rollback_slots if slot_api else None,
         paged_rollback=paged_rollback if slot_api else None,
     )
@@ -697,7 +741,7 @@ def apply_precision_plan(params, cfg: ModelConfig, plan: PrecisionPlan):
     construction (balanced plan) so banks stack cleanly."""
     assert cfg.moe is not None
     moe_p = params["layers"]["moe"]
-    l = cfg.num_layers
+    l = cfg.num_moe_layers
     banks_per_layer = []
     routers = []
     for li in range(l):

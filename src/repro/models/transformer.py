@@ -74,10 +74,13 @@ def _ffn_or_moe(p, xn, cfg: ModelConfig, par, train, use_kernel, aux_acc,
         banks = mixed_moe.train_banks(p["moe"])
     y = mixed_moe.moe_apply(banks, x2, weights, ids, cfg.moe, par,
                             act=cfg.act, use_kernel=use_kernel,
-                            capacity=moe_capacity)
+                            capacity=moe_capacity).reshape(b, s, d)
+    if "shared_mlp" in p:
+        # shared experts: every token, bf16, off the precision ladder
+        y = y + L.mlp(p["shared_mlp"], xn, cfg.act)
     for k, v in aux.items():
         aux_acc[k] = aux_acc.get(k, 0.0) + v
-    return y.reshape(b, s, d), aux_acc, ids
+    return y, aux_acc, ids
 
 
 def decoder_forward(params, cfg: ModelConfig, x, positions, *,
@@ -88,6 +91,10 @@ def decoder_forward(params, cfg: ModelConfig, x, positions, *,
     ``collect_routes=True`` (MoE serving) additionally stacks the per-layer
     routed expert ids into ``aux["route_ids"]`` (L, T, k) so the engine can
     drive the runtime expert cache (DESIGN.md §3).
+
+    ``caches`` may also be a function of the layer index that returns
+    that layer's cache (a paged pool read in place, latent attention);
+    ``new_caches`` are then the (L, B, S, ...) entries the layers wrote.
 
     ``spec=True`` (speculative decode, DESIGN.md §17) runs S>=1 new tokens
     through the LIVE-cache attention path (masked ring writes, no
@@ -111,7 +118,7 @@ def decoder_forward(params, cfg: ModelConfig, x, positions, *,
     # DIFFERENT tokens at draft vs verify widths, breaking exactness)
     moe_capacity = x.shape[0] * x.shape[1] if spec else None
 
-    def block(carry, xs):
+    def block(carry, xs, dense=False):
         x, aux = carry
         p, cache = xs
         h, new_kv = L.attention(
@@ -125,20 +132,55 @@ def decoder_forward(params, cfg: ModelConfig, x, positions, *,
                 cfg.attention, positions=positions, kv_x=enc_out)
             x = L.constrain(x + h, "residual")
         xn = L.rms_norm(x, p["ffn_norm"]["scale"])
-        h, aux, ids = _ffn_or_moe(p, xn, cfg, par, train, use_kernel, aux,
-                                  token_valid=token_valid,
-                                  moe_capacity=moe_capacity)
-        ys = (new_kv, ids) if collect_routes else new_kv
+        if dense:
+            h, ids = L.mlp(p["mlp"], xn, cfg.act), None
+        else:
+            h, aux, ids = _ffn_or_moe(p, xn, cfg, par, train, use_kernel,
+                                      aux, token_valid=token_valid,
+                                      moe_capacity=moe_capacity)
+        ys = (new_kv, ids) if collect_routes and not dense else new_kv
         return (L.constrain(x + h, "residual"), aux), ys
 
-    body = _maybe_remat(block, cfg)
-    (x, aux_total), ys = _scan_or_loop(
-        body, (x, aux_total), (params["layers"], caches), cfg)
+    k = cfg.first_dense_layers
+    stacks = [("layers", _maybe_remat(block, cfg), k, cfg.num_layers)]
+    if k:
+        # leading dense layers: the first k layers of the cache; their
+        # routes are not collected (no experts)
+        stacks.insert(0, ("dense_layers", _maybe_remat(
+            functools.partial(block, dense=True), cfg), 0, k))
+    ring, view = caches, None
+    if callable(caches):
+        # a per-layer view (a paged pool read in place): each layer
+        # returns only the entries it wrote
+        view = caches
+    elif caches is not None and k:
+        view = lambda i: jax.tree_util.tree_map(lambda a: a[i], ring)
+
+    def by_index(fn):
+        return lambda carry, xs: fn(carry, (xs[0], view(xs[1])))
+
+    outs = []
+    for stack, fn, lo, hi in stacks:
+        if view is None:
+            (x, aux_total), ys = _scan_or_loop(
+                fn, (x, aux_total), (params[stack], caches), cfg)
+        else:
+            (x, aux_total), ys = _scan_or_loop(
+                by_index(fn), (x, aux_total),
+                (params[stack], jnp.arange(lo, hi)), cfg)
+        outs.append(ys)
     if collect_routes:
-        new_caches, route_ids = ys
+        new_caches, route_ids = outs[-1]
         aux_total = dict(aux_total, route_ids=route_ids)
     else:
-        new_caches = ys
+        new_caches = outs[-1]
+    if k and new_caches is not None:
+        new_caches = jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b]), outs[0], new_caches)
+    if (ring is not None and not callable(ring) and x.shape[1] == 1
+            and cfg.attention.latent):
+        # a latent decode step returns the entries it wrote: store them
+        new_caches = L.write_entries(ring, new_caches)
     return x, new_caches, aux_total
 
 

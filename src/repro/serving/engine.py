@@ -279,7 +279,7 @@ class AdaptiveServingEngine:
         #: histogram must survive (placement-only) replans; callers
         #: window it via ``reset_route_counts()`` / their own snapshots.
         self.route_counts: np.ndarray = np.zeros(
-            (cfg.num_layers, cfg.moe.num_experts if cfg.moe else 0),
+            (cfg.num_moe_layers, cfg.moe.num_experts if cfg.moe else 0),
             np.int64)
         self._host_store: Dict[Tuple[int, int], Any] = {}
         self._resident: set = set()
@@ -946,7 +946,8 @@ class AdaptiveServingEngine:
                     self.cache, jnp.int32(slot))
 
     def _update_kv_metrics(self, active):
-        """Per-iteration KV padding accounting (DESIGN.md §13)."""
+        """Per-iteration KV padding accounting (DESIGN.md §13), and the
+        cache bytes the step attends (``kv_read_bytes``)."""
         tb = self._kv_token_bytes
         used = sum(min(st.position + 1, self.window)
                    for _, st in active) * tb
@@ -956,6 +957,7 @@ class AdaptiveServingEngine:
         else:
             alloc = self.max_slots * self.window * tb
         self.metrics["kv_used_bytes"] = used
+        self.metrics["kv_read_bytes"] += used
         self.metrics["kv_allocated_bytes"] = alloc
         self.metrics["kv_used_byte_iters"] += used
         self.metrics["kv_alloc_byte_iters"] += alloc
@@ -1410,7 +1412,8 @@ class AdaptiveServingEngine:
                   "prefill_padded_tokens", "decode_rows",
                   "expert_accesses", "expert_fetches", "iterations",
                   "kv_alloc_byte_iters", "kv_used_byte_iters",
-                  "spec_proposed", "spec_accepted", "acceptance_rate"):
+                  "kv_read_bytes", "spec_proposed", "spec_accepted",
+                  "acceptance_rate"):
             self.metrics[k] = 0 if isinstance(self.metrics[k], int) else 0.0
         self.expert_cache.stats.reset()
 

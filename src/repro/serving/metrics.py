@@ -65,6 +65,9 @@ ENGINE_METRIC_SCHEMA: Dict[str, Any] = {
     "kv_alloc_byte_iters": 0.0,
     "kv_used_byte_iters": 0.0,
     "kv_capacity_bytes": 0,
+    # cache bytes the decode steps attend: per step, each active row's
+    # attended positions x the cache's bytes a position over all layers
+    "kv_read_bytes": 0,
     # speculative decode (DESIGN.md §17): drafts proposed / accepted by
     # the verify forward; acceptance_rate = accepted / proposed so far
     "spec_proposed": 0,

@@ -171,3 +171,86 @@ def test_served_decode_step_compiles_with_every_rung(topo, monkeypatch):
     for name in ("grouped_q4_matmul", "grouped_q8_matmul",
                  "grouped_bf16_matmul"):
         assert any(name in ln for ln in calls), name
+
+
+#: (K, N) of DeepSeek-V2-Lite's expert projections: width 1408 = 11 x 128,
+#: which the default tiles do not divide; 48 rows per expert group (the
+#: dropless buffer of a 48-row decode batch)
+LITE_PROJ = {"up": (2048, 1408), "down": (1408, 2048)}
+LITE_EXPERTS, LITE_ROWS = 16, 48
+
+
+@pytest.mark.parametrize("proj", sorted(LITE_PROJ))
+@pytest.mark.parametrize("bits", [4, 8, 16])
+def test_grouped_kernels_compile_at_width_1408(one_chip, bits, proj):
+    k, n = LITE_PROJ[proj]
+    x = jax.ShapeDtypeStruct((LITE_EXPERTS, LITE_ROWS, k), jnp.bfloat16,
+                             sharding=one_chip)
+    if bits == 16:
+        w = jax.ShapeDtypeStruct((LITE_EXPERTS, k, n), jnp.bfloat16,
+                                 sharding=one_chip)
+        _assert_kernel(functools.partial(ops.grouped_bf16_matmul,
+                                         interpret=False),
+                       x, w, name="grouped_bf16_matmul")
+        return
+    q, sc = _q_args(one_chip, (LITE_EXPERTS,), k, n, bits)
+
+    def fn(x, q, sc):
+        return ops.grouped_q_matmul(
+            x, QTensor(q=q, scales=sc, bits=bits, group_size=GROUP),
+            interpret=False)
+    _assert_kernel(fn, x, q, sc, name=f"grouped_q{bits}_matmul")
+
+
+def test_served_latent_decode_step_compiles(topo, monkeypatch):
+    """The engine's paged decode step for DeepSeek-V2-Lite at published
+    widths (a dense layer and four expert layers, latent attention over
+    the latent pool) on a three-rung plan compiles for one chip, runs one
+    kernel per rung, and holds no more than two layers' page views in
+    temporaries: each layer reads its own pages in place."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.core.cost_model import HardwareModel
+    from repro.core.planner import AdaptivePlanner
+    from repro.models.model import (abstract_params, apply_precision_plan,
+                                    build_model, init_paged_cache)
+
+    monkeypatch.setattr(ops, "_interpret",
+                        lambda i: False if i is None else i)
+    cfg = get_config("deepseek-v2-lite")
+    cfg = cfg.replace(num_layers=5, mop=dataclasses.replace(
+        cfg.mop, ladder=(16, 8, 4)), moe=dataclasses.replace(
+            cfg.moe, capacity_factor=64 / 6))
+    plan = AdaptivePlanner(cfg, hw=HardwareModel()).replan(
+        1e12, "quality", counts={8: 64, 4: 128}, batch_size=8)[0].plan
+    assert set(np.unique(plan.bits)) == {4, 8, 16}
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                ("data", "model"))
+    repl = NamedSharding(mesh, P())
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl)
+    params = jax.tree_util.tree_map(placed, jax.eval_shape(
+        lambda p: apply_precision_plan(p, cfg, plan), abstract_params(cfg)))
+    slots, window = 8, 1024
+    pool, meta = init_paged_cache(cfg, slots, window, page_size=256,
+                                  abstract=True)
+    pool = jax.tree_util.tree_map(placed, pool)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=repl)
+    model = build_model(cfg, mesh, use_kernel=True)
+    step = functools.partial(model.paged_decode_step_routed,
+                             window=meta.window)
+    compiled = jax.jit(step).lower(
+        params, pool, i32(slots, meta.chunks_per_slot), i32(slots, 1),
+        i32(slots)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    for name in ("grouped_q4_matmul", "grouped_q8_matmul",
+                 "grouped_bf16_matmul"):
+        assert any(name in ln for ln in calls), name
+    view = slots * window * cfg.attention.kv_values_per_position * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        2 * view + (64 << 20)
