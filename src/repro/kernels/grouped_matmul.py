@@ -37,7 +37,6 @@ per-expert kernel (leading block of 1 on the group axis).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -45,29 +44,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.q4_matmul import dequant_tile, even_odd_k
-
-
-def pick_tile(dim: int, cap: int, quantum: int) -> int:
-    """The one tile chooser: the largest multiple of ``quantum`` that
-    divides ``dim`` and is <= max(cap, quantum), else the whole ``dim``.
-    Mosaic requires every block's last two dims to be multiples of
-    (8, 128) or to span the array; callers pass the quantum that makes
-    each of their BlockSpecs legal."""
-    best = dim
-    b = quantum
-    while b <= min(max(cap, quantum), dim):
-        if dim % b == 0:
-            best = b
-        b += quantum
-    return best
-
-
-def _fit(dim: int, block: int, quantum: int) -> int:
-    """``block`` where it divides ``dim`` (clamped to ``dim``), else the
-    tile :func:`pick_tile` chooses under it: a width such as
-    1408 = 11 x 128 takes N tiles of 128 and whole-K tiles."""
-    block = min(block, dim)
-    return block if dim % block == 0 else pick_tile(dim, block, quantum)
 
 
 def _grouped_q_kernel(x_ref, wq_ref, sc_ref, o_ref, acc_ref, *, nk: int,
@@ -121,9 +97,9 @@ def grouped_quantized_matmul(
 ) -> jax.Array:
     """``einsum('gck,gkn->gcn', x, dequant(wq, scales))`` in ONE launch.
 
-    BM must divide C (callers pad via :mod:`repro.kernels.ops`); BN and
-    BK are kept where they divide N and K, else chosen by
-    :func:`pick_tile` (BK whole groups).
+    Shape requirements match :func:`~repro.kernels.q4_matmul.
+    quantized_matmul` per group: BM|C, BN|N, BK|K, group_size|BK. Callers
+    pad via :mod:`repro.kernels.ops`.
     """
     g, c, kdim = x.shape
     if bits == 4:
@@ -142,11 +118,11 @@ def grouped_quantized_matmul(
         raise ValueError(
             f"scales {scales.shape[1:]} != {(kdim // group_size, n)}")
     block_m = min(block_m, c)
-    block_n = _fit(n, block_n, 128)
-    # K tiles hold whole groups, 8 scale rows at a time, or all of K
-    block_k = _fit(kdim, block_k, math.lcm(128, 8 * group_size))
-    if c % block_m:
-        raise ValueError(f"block_m {block_m} must divide C {c}")
+    block_n = min(block_n, n)
+    block_k = min(block_k, kdim)
+    if c % block_m or n % block_n or kdim % block_k:
+        raise ValueError(f"blocks must divide dims: "
+                         f"{(c, n, kdim)} vs {(block_m, block_n, block_k)}")
     if block_k % group_size:
         raise ValueError(f"group_size {group_size} must divide BK {block_k}")
 
@@ -196,10 +172,11 @@ def grouped_bf16_matmul(
     if gw != g or k_w != kdim:
         raise ValueError(f"shape mismatch: x {x.shape} vs w {w.shape}")
     block_m = min(block_m, c)
-    block_n = _fit(n, block_n, 128)
-    block_k = _fit(kdim, block_k, 128)
-    if c % block_m:
-        raise ValueError(f"block_m {block_m} must divide C {c}")
+    block_n = min(block_n, n)
+    block_k = min(block_k, kdim)
+    if c % block_m or n % block_n or kdim % block_k:
+        raise ValueError(f"blocks must divide dims: "
+                         f"{(c, n, kdim)} vs {(block_m, block_n, block_k)}")
     grid = (g, c // block_m, n // block_n, kdim // block_k)
     return pl.pallas_call(
         functools.partial(_grouped_bf16_kernel, nk=grid[3]),

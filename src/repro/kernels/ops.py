@@ -42,6 +42,21 @@ def serve_through_kernels() -> bool:
     return not _interpret(None)
 
 
+def _tile(dim: int, cap: int, quantum: int) -> int:
+    """The one tile chooser: the largest multiple of ``quantum`` that
+    divides ``dim`` and is <= max(cap, quantum), else the whole ``dim``.
+    Mosaic requires every block's last two dims to be multiples of
+    (8, 128) or to span the array; callers pass the quantum that makes
+    each of their BlockSpecs legal."""
+    best = dim
+    b = quantum
+    while b <= min(max(cap, quantum), dim):
+        if dim % b == 0:
+            best = b
+        b += quantum
+    return best
+
+
 def _q_tiles(qt: QTensor, block_n: int, block_k: int):
     """(block_n, block_k) for a quantized weight. K tiles hold whole
     multiples of 8 scale rows (``block_k // group_size``), which also
@@ -49,8 +64,8 @@ def _q_tiles(qt: QTensor, block_n: int, block_k: int):
     multiples of 128 lanes. Shared by the per-expert and grouped paths so
     both pick identical tiles (their bit-exact parity depends on it)."""
     k, n = qt.shape[-2:]
-    return (_gk.pick_tile(n, block_n, 128),
-            _gk.pick_tile(k, block_k, math.lcm(128, 8 * qt.group_size)))
+    return (_tile(n, block_n, 128),
+            _tile(k, block_k, math.lcm(128, 8 * qt.group_size)))
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -152,8 +167,7 @@ def grouped_bf16_matmul(x: jax.Array, w: jax.Array, *, block_m: int = 128,
     parity with the einsum reference is allclose, not bitwise)."""
     interpret = _interpret(interpret)
     _, k, n = w.shape
-    block_n = _gk.pick_tile(n, block_n, 128)
-    block_k = _gk.pick_tile(k, block_k, 128)
+    block_n, block_k = _tile(n, block_n, 128), _tile(k, block_k, 128)
     return _with_padded_m(
         lambda xp, bm: _gk.grouped_bf16_matmul(
             xp, w, block_m=bm, block_n=block_n, block_k=block_k,

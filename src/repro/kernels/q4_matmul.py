@@ -12,9 +12,9 @@ Design (DESIGN.md §2, hardware adaptation):
     K is the innermost (fastest) grid axis so the accumulator tile stays
     resident while weight tiles stream through.
   * Default tiles (BM, BN, BK) = (128, 256, 512), chosen by
-    ``grouped_matmul.pick_tile``: every block's last two dims are
-    multiples of (8, 128) or span the array, which Mosaic requires —
-    for group 64 that means BK/G >= 8 scale rows. VMEM per step = x(128x512xbf16 = 128 KiB) +
+    ``ops._tile``: every block's last two dims are multiples of (8, 128)
+    or span the array, which Mosaic requires — for group 64 that means
+    BK/G >= 8 scale rows. VMEM per step = x(128x512xbf16 = 128 KiB) +
     w(256x256 packed = 64 KiB) + scales(8x256xbf16 = 4 KiB) + acc
     (128x256xf32 = 128 KiB) + the dequantized f32 tile (512 KiB), well
     under v5e's scoped VMEM even double-buffered.

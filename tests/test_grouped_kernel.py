@@ -45,8 +45,7 @@ def assert_bit_equal(got, want):
 
 class TestGroupedQuantParity:
     #: (experts_in_group, capacity, K, N, group_size) — capacity sweeps
-    #: unaligned M tiles; K/N=192 fall back to whole-dim tiles
-    #: (grouped_matmul.pick_tile);
+    #: unaligned M tiles; K/N=192 fall back to whole-dim tiles (ops._tile);
     #: group_size=32 exercises a non-default scale granularity
     CASES = [
         (1, 8, 128, 128, 64),
@@ -109,40 +108,38 @@ class TestGroupedQuantParity:
 
 class TestWidthOf11Tiles:
     """DeepSeek-V2-Lite's expert width 1408 = 11 x 128, which the default
-    tiles (BN 256, BK 512) do not divide: the kernels themselves take N
-    tiles of 128 and K tiles that divide K in whole groups (all of K for
-    the quantized rungs, 128 for bf16), for every rung, in interpret
-    mode against float64 dequantize-then-matmul."""
+    tiles (BN 256, BK 512) do not divide: the served entry points choose
+    N tiles of 128 and K tiles that divide K in whole groups (all of K for
+    the quantized rungs, 128 for bf16), for every rung, in interpret mode
+    against float64 dequantize-then-matmul."""
     WIDTH = 11 * 128
 
     def test_tiles_divide_the_width(self):
-        from repro.kernels.grouped_matmul import _fit
+        from repro.kernels.ops import _q_tiles, _tile
         w = self.WIDTH
-        assert _fit(w, 256, 128) == 128
-        assert _fit(w, 512, 512) == w          # q: 8 scale rows of 64
-        assert _fit(w, 512, 128) == 128        # bf16
-        assert _fit(14336, 256, 128) == 256    # Mixtral keeps its tiles
-        assert _fit(4096, 512, 512) == 512
+        _, qt = make_bank(1, 8, w, w, 8, 64)
+        assert _q_tiles(qt, 256, 512) == (128, w)   # 8 scale rows of 64
+        assert _tile(w, 512, 128) == 128            # bf16
+        assert _tile(14336, 256, 128) == 256        # Mixtral keeps its tiles
+        assert _tile(4096, 512, 512) == 512
 
     @pytest.mark.parametrize("bits", [4, 8, 16])
     def test_every_rung_at_width_1408(self, bits):
         from repro.core.quantization import dequantize, unpack_int4
-        from repro.kernels import grouped_matmul as gk
         w = self.WIDTH
         x, qt = make_bank(2, 16, w, w, 8 if bits == 16 else bits, 64,
                           seed=bits)
         if bits == 16:
             wf = np.asarray(dequantize(qt), np.float64)
-            got = gk.grouped_bf16_matmul(
+            got = grouped_bf16_matmul(
                 x, jnp.asarray(wf, jnp.bfloat16), out_dtype=jnp.float32,
                 interpret=True)
         else:
             codes = unpack_int4(qt.q) if bits == 4 else qt.q
             step = np.repeat(np.asarray(qt.scales, np.float64), 64, axis=1)
             wf = np.asarray(codes, np.float64) * step
-            got = gk.grouped_quantized_matmul(
-                x, qt.q, qt.scales, bits=bits, group_size=64,
-                out_dtype=jnp.float32, interpret=True)
+            got = grouped_q_matmul(x, qt, out_dtype=jnp.float32,
+                                   interpret=True)
         want = np.einsum("eck,ekn->ecn", np.asarray(x, np.float64), wf)
         # float32 accumulation of the same products, in another order
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
